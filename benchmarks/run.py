@@ -342,6 +342,9 @@ def main() -> None:
                     help="with --corpus: exit non-zero when 'near' prediction "
                          "accuracy drops below this fraction (CI gate)")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     if args.corpus:
         summary = run_corpus(args.corpus, args.json)

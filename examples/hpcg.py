@@ -19,6 +19,7 @@ import argparse
 
 import jax
 
+from repro import compile_cache
 from repro.apps.hpcg import run_hpcg, run_hpcg_distributed
 
 
@@ -32,6 +33,7 @@ def main():
                     help="disable the multigrid preconditioner (plain CG)")
     ap.add_argument("--distributed", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     g = args.grid
     if args.distributed:

@@ -23,7 +23,9 @@ import numpy as np
 from .convert import col_tile_for_policy as _col_tile_for_policy
 from .convert import container_to_scipy as _container_to_scipy
 from .convert import from_dense as _from_dense
+from .errors import BackendUnsupportedError
 from .operator import DEFAULT_POLICY, ExecutionPolicy, SparseOperator
+from .select import DENSE_MAX_BYTES
 from .spmv import DispatchKey, available_impls, spmv
 
 DEFAULT_CANDIDATES: Tuple[DispatchKey, ...] = (
@@ -83,7 +85,8 @@ def _normalize_candidates(candidates) -> Tuple[Tuple[str, str], ...]:
 
 def structural_skip(s, fmt: str, dia_max_diags: int = 512,
                     ell_max_width_factor: float = 4.0,
-                    bsr_min_block_fill: float = 0.125) -> Optional[str]:
+                    bsr_min_block_fill: float = 0.125,
+                    dense_max_bytes: int = DENSE_MAX_BYTES) -> Optional[str]:
     """Why ``fmt`` should not even be *built* for matrix ``s`` — or ``None``.
 
     The practical limits Morpheus applies before racing a candidate
@@ -91,7 +94,8 @@ def structural_skip(s, fmt: str, dia_max_diags: int = 512,
     when the matrix has too many distinct diagonals, ELL when the max row
     width far exceeds the mean (power-law rows pad catastrophically), BSR
     when the 32-edge block fill is so low its zero-padded blocks blow up
-    storage. Shared by the single-matrix tuner below and the per-partition
+    storage, dense when the f32 n x m array would pass ``dense_max_bytes``.
+    Shared by the single-matrix tuner below and the per-partition
     distributed tuner, so every tuning path applies identical guards.
 
     Args:
@@ -102,6 +106,7 @@ def structural_skip(s, fmt: str, dia_max_diags: int = 512,
             is skipped.
         bsr_min_block_fill: min nnz / occupied 32-block area before BSR is
             skipped.
+        dense_max_bytes: max f32 bytes of the densified matrix.
 
     Returns:
         A human-readable skip reason, or ``None`` when the format is fine.
@@ -111,8 +116,8 @@ def structural_skip(s, fmt: str, dia_max_diags: int = 512,
         >>> structural_skip(sp.eye(64, format="csr"), "dia") is None
         True
     """
-    import scipy.sparse as sp
-
+    if fmt == "dense" and 4 * s.shape[0] * s.shape[1] > dense_max_bytes:
+        return f"dense={4 * s.shape[0] * s.shape[1]}B>{dense_max_bytes}B"
     s = s.tocsr()
     if s.nnz and not s.data.all():
         # guard on *logical* nonzeros, exactly like the feature-level mirror
@@ -161,6 +166,12 @@ def autotune_spmv(
     limits: DIA is not built when the matrix has too many distinct diagonals
     (memory blow-up — the paper's FPGA section calls out exactly this), ELL
     when max row width far exceeds the mean (power-law matrices).
+
+    Each candidate races under a *strict* policy (its backend alone, no
+    fallback), so a timing always belongs to the kernel named on it. A
+    backend whose capability predicate refuses the built container is
+    skipped with reason ``"unsupported"``; a candidate that raises anything
+    else fails the tune — a broken kernel is an error, not a slow entry.
 
     ``prune=k`` races only the top-``k`` candidates of the zero-run
     selector's ranking (``core/select.py``) — run-first stays the oracle
@@ -230,7 +241,8 @@ def autotune_spmv(
                 kw["col_tile"] = _col_tile_for_policy(fmt, n, base.col_tile(n))
             mats[fmt] = _from_dense(s, fmt, **kw)
         A = mats[fmt]
-        pol = (policy if policy is not None else DEFAULT_POLICY).preferring(impl)
+        pol = (policy if policy is not None else DEFAULT_POLICY).replace(
+            backends=(impl,), allow_fallback=False)
         fn = jax.jit(lambda A, x, pol=pol: spmv(A, x, policy=pol))
         try:
             if time_fn is not None:
@@ -238,8 +250,8 @@ def autotune_spmv(
                                              iters=iters, warmup=warmup)
             else:
                 table[(fmt, impl)] = _time_call(fn, A, x, iters=iters, warmup=warmup)
-        except Exception as e:  # pragma: no cover - impl-specific lowering gaps
-            skipped.append((fmt, impl, f"error: {type(e).__name__}"))
+        except BackendUnsupportedError:
+            skipped.append((fmt, impl, "unsupported"))
 
     if not table:
         raise RuntimeError("auto-tuner: no candidate succeeded")

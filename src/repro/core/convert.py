@@ -47,9 +47,12 @@ def _as_scipy(a) -> sp.csr_matrix:
     if sp.issparse(a):
         return a.tocsr()
     if hasattr(a, "to_dense"):  # registered sparse container
-        a = a.to_dense()
-    a = np.asarray(a)
-    return sp.csr_matrix(a)
+        s = container_to_scipy(a)  # COO/CSR without densifying
+        if not s.data.all():  # drop stored zeros, as the dense round trip would
+            s = s.copy()
+            s.eliminate_zeros()
+        return s
+    return sp.csr_matrix(np.asarray(a))
 
 
 def _as_scipy_sorted(a) -> sp.csr_matrix:
@@ -72,10 +75,42 @@ def from_dense(a, fmt: str, dtype=jnp.float32, **kw):
     return builders[fmt](a, dtype=dtype, **kw)
 
 
+def _padded_triplets(c):
+    """(row, col, val) host arrays of a DIA/ELL/SELL/BSR container, pad and
+    out-of-range slots included (the caller filters them)."""
+    if c.format == "dia":
+        offsets, data = np.asarray(c.offsets), np.asarray(c.data)
+        row = np.broadcast_to(np.arange(data.shape[1]), data.shape)
+        return row, row + offsets[:, None].astype(np.int64), data
+    if c.format == "ell":
+        idx = np.asarray(c.indices)
+        return (np.broadcast_to(np.arange(idx.shape[0])[:, None], idx.shape),
+                idx, np.asarray(c.data))
+    if c.format == "sell":
+        base = np.asarray(c.sptr).astype(np.int64) * c.C
+        e = np.arange(c.data.shape[0])
+        s = np.searchsorted(base, e, side="right") - 1  # slice of each entry
+        row = np.asarray(c.perm)[s * c.C + (e - base[s]) % c.C]
+        return row, np.asarray(c.indices), np.asarray(c.data)
+    if c.format == "bsr":
+        bcols, blocks = np.asarray(c.bcols), np.asarray(c.blocks)
+        nbrows, bwidth, bs, _ = blocks.shape
+        r = np.arange(nbrows)[:, None, None, None] * bs + np.arange(bs)[:, None]
+        col = bcols[:, :, None, None].astype(np.int64) * bs + np.arange(bs)
+        # pad blocks (bcol=-1) land at negative columns and are filtered out
+        col = np.where(bcols[:, :, None, None] >= 0, col, -1)
+        return (np.broadcast_to(r, blocks.shape),
+                np.broadcast_to(col, blocks.shape), blocks)
+    raise TypeError(f"no sparse host view for format {c.format!r}")
+
+
 def container_to_scipy(c) -> sp.csr_matrix:
-    """Registered container -> scipy CSR without densifying where the format
-    allows (COO/CSR carry their triplets directly; pad sentinels dropped).
-    Other formats go via ``to_dense`` — the exactness-only route."""
+    """Registered container -> scipy CSR without densifying (pad sentinels
+    and out-of-range slots dropped). Only ``dense`` round-trips its array.
+
+    COO/CSR keep their stored entries; the padded formats drop stored zeros,
+    as the dense round trip always did, so a conversion never turns padding
+    into structure (e.g. extra DIA diagonals)."""
     nrows, ncols = (int(d) for d in c.shape)
     if c.format == "coo":
         row, col, val = (np.asarray(x) for x in (c.row, c.col, c.val))
@@ -86,7 +121,14 @@ def container_to_scipy(c) -> sp.csr_matrix:
         nnz = int(indptr[-1])  # trailing entries past indptr[-1] are padding
         return sp.csr_matrix((np.asarray(c.data)[:nnz], np.asarray(c.indices)[:nnz],
                               indptr), shape=(nrows, ncols))
-    return sp.csr_matrix(np.asarray(c.to_dense()))
+    if c.format == "dense":
+        return sp.csr_matrix(np.asarray(c.data))
+    row, col, val = (np.ravel(a) for a in _padded_triplets(c))
+    keep = (row < nrows) & (col >= 0) & (col < ncols) & (val != 0)
+    s = sp.csr_matrix((val[keep], (row[keep], col[keep])), shape=(nrows, ncols))
+    s.sum_duplicates()  # sorted indices, like the dense round trip
+    s.eliminate_zeros()
+    return s
 
 
 def convert(A, fmt: str, **kw):
@@ -161,13 +203,13 @@ def to_csr(a, dtype=jnp.float32, col_tile: ColTile = None, plan: bool = True,
 def to_dia(a, dtype=jnp.float32, col_tile: ColTile = None):
     s = _as_scipy(a).tocoo()
     nrows, ncols = s.shape
-    offs = np.unique(s.col.astype(np.int64) - s.row.astype(np.int64))
+    entry_offs = s.col.astype(np.int64) - s.row.astype(np.int64)
+    offs = np.unique(entry_offs)
     if len(offs) == 0:
         offs = np.array([0], np.int64)
     data = np.zeros((len(offs), nrows), np.float64)
-    dmap = {int(o): i for i, o in enumerate(offs)}
-    for r, c, v in zip(s.row, s.col, s.data):
-        data[dmap[int(c) - int(r)], r] += v
+    # unbuffered add: duplicate entries accumulate, in entry order
+    np.add.at(data, (np.searchsorted(offs, entry_offs), s.row), s.data)
     ct = _resolve_col_tile(ncols, col_tile)
     plan = None
     if ct is not None:
@@ -249,8 +291,9 @@ def to_sell(a, dtype=jnp.float32, C: int = 8, sigma: int = 64,
     dat[tgt] = s.data[src]
     scs = None
     if plan and col_tile is not False and col_tile != 0:
+        # the Pallas stream is 128 lanes wide whatever this container's C
         scs = tiling.build_scs_plan(
-            s, col_tile=_resolve_col_tile(ncols, col_tile), C=C, sigma=sigma,
+            s, col_tile=_resolve_col_tile(ncols, col_tile), sigma=sigma,
             dtype=np.dtype(dtype), index_dtype=index_dtype).jaxify()
     return SELL(jnp.asarray(sptr, jnp.int32), jnp.asarray(idx), jnp.asarray(dat, dtype),
                 jnp.asarray(perm, jnp.int32), (nrows, ncols), C, scs)

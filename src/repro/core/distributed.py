@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .convert import to_coo, to_csr, to_dia, to_ell
 from .operator import ExecutionPolicy, policy_for_impl
@@ -133,20 +133,18 @@ def split_local_remote(s: sp.spmatrix, nparts: int, halo="auto"):
         mr = r1 - r0
         blk = s[r0:r1]
         local = blk[:, c0:c1].tocsr()
-        rem = blk.tolil(copy=True)
-        rem[:, c0:c1] = 0
-        rem = rem.tocsr()
-        rem.eliminate_zeros()
+        # remote: the nonzero entries outside the own columns, selected
+        # entry-wise (zeroing a column range of a LIL matrix builds dense
+        # (mr, mc) index arrays, hundreds of GB at HPCG's grid sizes)
+        rc = blk.tocoo()
+        off = ((rc.col < c0) | (rc.col >= c1)) & (rc.data != 0)
+        rows, cols, width = rc.row[off], rc.col[off], nc
         if halo is not None:
-            w0 = c0 - halo
-            win = sp.lil_matrix((mr, mc + 2 * halo), dtype=s.dtype)
-            rc = rem.tocoo()
-            cols = rc.col - w0
-            keep = (cols >= 0) & (cols < mc + 2 * halo)
-            assert keep.all(), "halo window does not cover remote entries"
-            win[rc.row, cols] = rc.data
-            rem = win.tocsr()
-        remotes.append(rem)
+            cols, width = cols - (c0 - halo), mc + 2 * halo
+            assert ((cols >= 0) & (cols < width)).all(), \
+                "halo window does not cover remote entries"
+        remotes.append(sp.csr_matrix((rc.data[off], (rows, cols)),
+                                     shape=(mr, width), dtype=s.dtype))
         locals_.append(local)
     return locals_, remotes, halo
 
@@ -285,7 +283,7 @@ class DistributedSpMV:
         spec = P(self.axis)
         fn = shard_map(
             self._shard_fn, mesh=self.mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
         )
         return fn(self.local, self.remote, x)
 

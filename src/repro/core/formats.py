@@ -74,6 +74,17 @@ class KernelPlan:
         return None if pos is None else jnp.dtype(self.arrays[pos].dtype)
 
 
+def segment_ids(bounds: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Segment of each position ``0..n-1`` given sorted segment starts
+    ``bounds`` (``bounds[0] == 0``): ``searchsorted(bounds, arange(n),
+    side="right") - 1``, with positions past ``bounds[-1]`` in segment
+    ``len(bounds) - 1``. Computed as a scatter of one mark per segment end
+    and a running sum: a binary search per position takes seconds on a TPU
+    at HPCG's 30M entries, this takes milliseconds."""
+    marks = jnp.zeros((n + 1,), jnp.int32).at[bounds[1:]].add(1)
+    return jnp.cumsum(marks, dtype=jnp.int32)[:n]
+
+
 jax.tree_util.register_pytree_node(
     KernelPlan,
     lambda p: (p.arrays, (p.kind, p.meta)),
@@ -168,9 +179,7 @@ class CSR:
 
     def row_ids(self) -> jnp.ndarray:
         """Expand indptr back to per-entry row ids (the COO 'ai' array)."""
-        nnz = self.data.shape[0]
-        # row of entry e = number of row boundaries <= e, minus 1
-        return jnp.searchsorted(self.indptr, jnp.arange(nnz, dtype=jnp.int32), side="right").astype(jnp.int32) - 1
+        return segment_ids(self.indptr, self.data.shape[0])
 
     def to_dense(self) -> jnp.ndarray:
         nrows, ncols = self.shape
@@ -306,7 +315,7 @@ class SELL:
         total = self.data.shape[0]
         e = jnp.arange(total, dtype=jnp.int32)
         base = self.sptr * self.C
-        s = jnp.searchsorted(base, e, side="right").astype(jnp.int32) - 1
+        s = segment_ids(base, total)
         lane = (e - base[s]) % self.C
         return self.perm[s * self.C + lane]
 

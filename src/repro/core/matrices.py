@@ -96,13 +96,13 @@ def block_random(n: int, bs: int = 32, block_density: float = 0.05,
     nb = -(-n // bs)
     mask = rng.random((nb, nb)) < block_density
     mask[np.arange(nb), np.arange(nb)] = True
-    rows, cols, vals = [], [], []
-    for br, bc in zip(*np.nonzero(mask)):
-        blk = rng.standard_normal((bs, bs))
-        r0, c0 = br * bs, bc * bs
-        for i in range(min(bs, n - r0)):
-            for j in range(min(bs, n - c0)):
-                rows.append(r0 + i), cols.append(c0 + j), vals.append(blk[i, j])
+    br, bc = np.nonzero(mask)
+    blk = rng.standard_normal((len(br), bs, bs))  # block by block, row-major
+    off = np.arange(bs)
+    rows = np.broadcast_to((br * bs)[:, None, None] + off[None, :, None], blk.shape)
+    cols = np.broadcast_to((bc * bs)[:, None, None] + off[None, None, :], blk.shape)
+    keep = (rows < n) & (cols < n)  # edge blocks are clipped to the matrix
+    rows, cols, vals = rows[keep], cols[keep], blk[keep]
     return sp.csr_matrix((vals, (rows, cols)),
                          shape=(n, n)).astype(dtype, copy=False)
 

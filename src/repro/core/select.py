@@ -53,6 +53,10 @@ ELL_MAX_WIDTH_FACTOR = 4.0
 #: the zero-padded blocks blow storage past 1/BSR_MIN_BLOCK_FILL x the
 #: logical nonzeros, and the block lane loses to CSR/SELL on pure volume.
 BSR_MIN_BLOCK_FILL = 0.125
+#: The dense candidate is refused when its f32 n x m array would pass this:
+#: 256 MiB holds an 8192 x 8192 matrix, while HPCG's 104³ operator would
+#: need ~5 TB.
+DENSE_MAX_BYTES = 1 << 28
 
 #: Calibrated cost tables: platform -> (fmt, backend, strategy) ->
 #: (a_us, b_us_per_krow, c_us_per_kentry, d_us_per_krow_kentry) — the four
@@ -251,6 +255,7 @@ def infeasible(f: MatrixFeatures, fmt: str,
                dia_max_diags: int = DIA_MAX_DIAGS,
                ell_max_width_factor: float = ELL_MAX_WIDTH_FACTOR,
                bsr_min_block_fill: float = BSR_MIN_BLOCK_FILL,
+               dense_max_bytes: int = DENSE_MAX_BYTES,
                ) -> Optional[str]:
     """Feature-level mirror of ``autotune.structural_skip``: why ``fmt``
     should not even be built, or ``None``. Computed from features alone so
@@ -269,6 +274,8 @@ def infeasible(f: MatrixFeatures, fmt: str,
             return f"max_row={f.rownnz_max} >> mean={mean_w:.1f}"
     if fmt == "bsr" and f.nnz and f.block_density32 < bsr_min_block_fill:
         return f"block_fill={f.block_density32:.3f}<{bsr_min_block_fill}"
+    if fmt == "dense" and 4 * f.nrows * f.ncols > dense_max_bytes:
+        return f"dense={4 * f.nrows * f.ncols}B>{dense_max_bytes}B"
     return None
 
 

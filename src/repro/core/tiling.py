@@ -128,28 +128,50 @@ def _cumcount_sorted(group: np.ndarray) -> np.ndarray:
 
 
 def build_ell_col_plan(s, col_tile: int, dtype=np.float32,
-                       index_dtype="auto") -> KernelPlan:
-    """Split a (sorted) scipy CSR matrix into per-column-tile ELL blocks.
+                       index_dtype="auto", block_rows: int = 256) -> KernelPlan:
+    """Split a (sorted) scipy CSR matrix into per-(row block, column tile)
+    ELL panels.
 
-    Arrays: ``idx_t (ntiles, nrows, W)`` tile-local columns (-1 pad) in the
-    narrowest dtype the tile width allows (see :func:`local_index_dtype`)
-    and ``dat_t`` alike; ``W`` is the max per-(row, tile) entry count. Each
-    grid step of the tiled ELL kernel owns one dense (row-block, tile) pair.
+    Only pairs that hold entries get a panel (plus one all-padding panel for
+    a row block with none, so its y rows are still written): storage tracks
+    the matrix's tile coverage, not ``ntiles * nrows`` — a banded matrix
+    touches two or three tiles per row block however many tiles there are.
+
+    Arrays: ``idx_t (P, W, br)`` tile-local columns (-1 pad) in the
+    narrowest dtype the tile width allows (see :func:`local_index_dtype`),
+    ``dat_t`` alike, ``prb (P,)`` / ``pt (P,)`` int32 each panel's row block
+    and column tile, row-block-major; ``W`` is the max per-(row, tile) entry
+    count and ``br`` the row-block height (a multiple of 128). Rows run
+    along the last axis, the TPU's 128 lanes: a narrow ``W`` there would be
+    padded to 128 lanes in device memory. Meta: ``(ct, ntiles, W, br)``.
     """
     nrows, ncols = s.shape
     ntiles = max(1, _cdiv(ncols, col_tile))
     idt = local_index_dtype(col_tile, index_dtype)
+    br = min(block_rows, _cdiv(max(nrows, 1), 128) * 128)
+    nrb = _cdiv(max(nrows, 1), br)
     counts = np.diff(s.indptr)
     r = np.repeat(np.arange(nrows, dtype=np.int64), counts)
     c = s.indices.astype(np.int64)
     t = c // col_tile
     j = _cumcount_sorted(r * ntiles + t)  # CSR order: sorted by (row, col)
     width = int(j.max()) + 1 if len(j) else 1  # max group size, O(nnz)
-    idx_t = np.full((ntiles, nrows, width), -1, idt)
-    dat_t = np.zeros((ntiles, nrows, width), dtype)
-    idx_t[t, r, j] = (c - t * col_tile).astype(idt)
-    dat_t[t, r, j] = s.data
-    return KernelPlan("ell-cols", (idx_t, dat_t), (col_tile, ntiles, width))
+    pair = (r // br) * ntiles + t
+    present = np.zeros((nrb, ntiles), bool)
+    present.reshape(-1)[pair] = True
+    present[~present.any(axis=1), 0] = True  # empty row block: one pad panel
+    pids = np.flatnonzero(present)  # sorted: row-block-major, tile-minor
+    panel = np.zeros(nrb * ntiles, np.int64)
+    panel[pids] = np.arange(len(pids))
+    idx_t = np.full((len(pids), width, br), -1, idt)
+    dat_t = np.zeros((len(pids), width, br), dtype)
+    p = panel[pair]
+    idx_t[p, j, r % br] = (c - t * col_tile).astype(idt)
+    dat_t[p, j, r % br] = s.data
+    prb = (pids // ntiles).astype(np.int32)
+    pt = (pids % ntiles).astype(np.int32)
+    return KernelPlan("ell-cols", (idx_t, dat_t, prb, pt),
+                      (col_tile, ntiles, width, br))
 
 
 # ------------------------------------------------------------ DIA splitter ----
@@ -259,7 +281,7 @@ def build_coo_col_plan(row: np.ndarray, col: np.ndarray, val: np.ndarray,
 # ---------------------------------------------- SELL-C-sigma (SCS) splitter ----
 
 
-def build_scs_plan(s, col_tile: Optional[int] = None, C: int = 8,
+def build_scs_plan(s, col_tile: Optional[int] = None, C: int = 128,
                    sigma: int = 64, slice_window: int = 4,
                    jstep_block: int = 32, dtype=np.float32,
                    index_dtype="auto") -> KernelPlan:
@@ -268,7 +290,9 @@ def build_scs_plan(s, col_tile: Optional[int] = None, C: int = 8,
     Rows are permuted by descending nnz inside σ-windows (Kreutzer et al.'s
     regularisation of CSR for wide SIMD), grouped into slices of C lanes, and
     each slice's entries emitted as *j-steps*: one C-lane vector per within-
-    row position. J-steps are bucketed by (slice-window, column tile) —
+    row position. ``C`` defaults to the TPU's 128 lanes: the ``(B*JB, C)``
+    arrays are tiled ``(8, 128)`` in device memory, so a narrower C pads
+    every panel out to 128 lanes (16x at C = 8). J-steps are bucketed by (slice-window, column tile) —
     window-major, tile-minor — and each bucket padded to ``jstep_block``
     j-steps, so every kernel grid step owns a dense (jstep_block, C) panel,
     its scalar-prefetched ``btile``/``bwin`` steer the x tile + output window

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _quant(x: jnp.ndarray, chunk: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -96,6 +96,6 @@ class CompressedAllReduce:
 
         fn = shard_map(inner, mesh=self.mesh,
                        in_specs=(P(self.axis), P(self.axis)),
-                       out_specs=(P(self.axis), P(self.axis)), check_rep=False)
+                       out_specs=(P(self.axis), P(self.axis)), check_vma=False)
         red, new_err = fn(vec_stacked, err_stacked)
         return red.mean(axis=0), new_err  # all rows identical; mean collapses

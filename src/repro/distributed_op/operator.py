@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import health as _health
 from repro.core.convert import _as_scipy
@@ -114,6 +114,8 @@ def _per_part_keys(spec, nparts: int) -> Tuple[DispatchKey, ...]:
     return keys
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["container"],
+         meta_fields=["key", "members"])
 @dataclass(frozen=True)
 class FormatGroup:
     """Ranks sharing one (format, backend) choice + their stacked container.
@@ -165,9 +167,11 @@ def _build_groups(mats: Sequence[sp.spmatrix], keys: Sequence[DispatchKey],
 class DistributedOperator:
     """Row-sharded sparse linear operator: ``A @ x`` under ``shard_map``.
 
-    Built with :meth:`build` (or the :func:`distribute` convenience). The
-    operator closes over its stacked containers; callers jit *around* it
-    (``jax.jit(lambda b: cg(op, b, ...))``) exactly like ``SparseOperator``.
+    Built with :meth:`build` (or the :func:`distribute` convenience). Like
+    ``SparseOperator`` it is a pytree whose leaves are the stacked
+    containers, so a jitted solver takes it as an argument
+    (``jax.jit(lambda op, b: cg(op, b, ...))(op, b)``); the host-side
+    ``source`` matrix does not survive flattening.
 
     Attributes:
         mesh / axis: the 1-D device axis rows are sharded over.
@@ -334,11 +338,11 @@ class DistributedOperator:
         if mask is None:
             fn = shard_map(partial(self._shard_fn, None), mesh=self.mesh,
                            in_specs=(spec, spec, spec), out_specs=spec,
-                           check_rep=False)
+                           check_vma=False)
             return fn(lc, rc, x)
         fn = shard_map(self._shard_fn, mesh=self.mesh,
                        in_specs=(spec, spec, spec, spec), out_specs=spec,
-                       check_rep=False)
+                       check_vma=False)
         return fn(mask, lc, rc, x)
 
     # the per-shard program: local SpMV overlapped with the halo exchange
@@ -432,6 +436,15 @@ class DistributedOperator:
             ("allgather" if self.halo is None else "auto"),
             policy=self.base_policy, dtype=self.dtype, **kw)
         return op
+
+
+jax.tree_util.register_pytree_node(
+    DistributedOperator,
+    lambda op: ((op.local_groups, op.remote_groups),
+                (op.mesh, op.axis, op.shape, op.dtype, op.halo, op.mode,
+                 op.choices, op.base_policy)),
+    lambda aux, groups: DistributedOperator(*aux[:6], *groups, *aux[6:]),
+)
 
 
 def distribute(a, mesh: Mesh, axis: str = "data", **kw) -> DistributedOperator:

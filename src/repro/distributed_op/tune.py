@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -95,18 +96,21 @@ def tune_partitions(
         s, nparts, halo=None if mode == "allgather" else "auto")
 
     lkeys, rkeys, table = [], [], {}
+    devices = mesh.devices.reshape(-1)  # shard p lives on the p-th device
     for p in range(nparts):
-        res = autotune_spmv(locals_[p], candidates=cand, iters=iters,
-                            warmup=warmup, policy=policy, dtype=dtype)
-        lkeys.append(res.key)
-        table[(p, "local")] = res.table
-        if remotes[p].nnz == 0:
-            rkeys.append(_EMPTY_CHOICE)
-            continue
-        res = autotune_spmv(remotes[p], candidates=cand, iters=iters,
-                            warmup=warmup, policy=policy, dtype=dtype)
-        rkeys.append(res.key)
-        table[(p, "remote")] = res.table
+        # each rank measures on its own device, as a process would
+        with jax.default_device(devices[p]):
+            res = autotune_spmv(locals_[p], candidates=cand, iters=iters,
+                                warmup=warmup, policy=policy, dtype=dtype)
+            lkeys.append(res.key)
+            table[(p, "local")] = res.table
+            if remotes[p].nnz == 0:
+                rkeys.append(_EMPTY_CHOICE)
+                continue
+            res = autotune_spmv(remotes[p], candidates=cand, iters=iters,
+                                warmup=warmup, policy=policy, dtype=dtype)
+            rkeys.append(res.key)
+            table[(p, "remote")] = res.table
 
     op = DistributedOperator.build(s, mesh, axis, local=tuple(lkeys),
                                    remote=tuple(rkeys), mode=mode,

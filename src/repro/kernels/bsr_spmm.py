@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import interpret_mode
+
 
 def _kernel(bcols_ref, blocks_ref, x_ref, y_ref, *, bwidth: int):
     b = pl.program_id(0)
@@ -37,7 +39,9 @@ def _kernel(bcols_ref, blocks_ref, x_ref, y_ref, *, bwidth: int):
     valid = (bc >= 0).astype(jnp.float32)
     blk = blocks_ref[0, 0].astype(jnp.float32)
     xt = x_ref[...].astype(jnp.float32)
-    y_ref[...] += valid * jnp.dot(blk, xt, preferred_element_type=jnp.float32)
+    # f32 in full: the MXU's default is one bf16 pass (~1e-3 relative error)
+    y_ref[...] += valid * jnp.dot(blk, xt, preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("nf_tile", "interpret"))
@@ -46,8 +50,6 @@ def bsr_spmm(bcols: jnp.ndarray, blocks: jnp.ndarray, X: jnp.ndarray,
     """Y = A @ X. bcols: (nbrows, bwidth) int32 (-1 pad); blocks:
     (nbrows, bwidth, bs, bs); X: (ncols, nf) with ncols >= max(bcols+1)*bs.
     Returns (nbrows*bs, nf) f32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nbrows, bwidth = bcols.shape
     bs = blocks.shape[-1]
     ncols, nf = X.shape
@@ -79,6 +81,6 @@ def bsr_spmm(bcols: jnp.ndarray, blocks: jnp.ndarray, X: jnp.ndarray,
             out_specs=pl.BlockSpec((bs, nf_tile), lambda b, f, w, bc: (b, f)),
         ),
         out_shape=jax.ShapeDtypeStruct((nbrows * bs, nf_pad), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(flat_bcols, blocks, Xp)
     return y[:, :nf]

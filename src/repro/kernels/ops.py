@@ -168,8 +168,9 @@ def dia_spmv_pallas(A: DIA, x, policy):
 def ell_spmv_pallas(A: ELL, x, policy):
     if pallas_strategy(A, policy) == "resident":
         return ell_spmv(A.indices, A.data, x)
-    idx_t, dat_t = A.plan.arrays
-    return ell_spmv_tiled(idx_t, dat_t, x, col_tile=A.plan.ct)
+    idx_t, dat_t, prb, pt = A.plan.arrays
+    return ell_spmv_tiled(idx_t, dat_t, prb, pt, x, nrows=A.shape[0],
+                          col_tile=A.plan.ct)
 
 
 @register_spmv("coo", "pallas", supports=_coo_ok, needs_policy=True)
@@ -177,10 +178,9 @@ def coo_spmv_pallas(A: COO, x, policy):
     if pallas_strategy(A, policy) == "resident":
         return coo_spmv(A.row, A.col, A.val, x, nrows=A.shape[0])
     row, col, val, sid, ctile = A.plan.arrays
-    ct, ntiles, slice_rows, tile = (int(v) for v in A.plan.meta)
+    ct, _, slice_rows, tile = (int(v) for v in A.plan.meta)
     return scoo_spmv_tiled(row, col, val, sid, ctile, x, nrows=A.shape[0],
-                           col_tile=ct, ntiles=ntiles,
-                           slice_rows=slice_rows, tile=tile)
+                           col_tile=ct, slice_rows=slice_rows, tile=tile)
 
 
 @register_spmv("sell", "pallas", supports=_scs_ok)
@@ -197,30 +197,22 @@ def csr_spmv_pallas(A: CSR, x):
     return scs_spmv_from_plan(A.plan, x, nrows=A.shape[0])
 
 
-# Row-masked variants (multicolor SymGS colors): the mask is applied to the
-# *operand* — rows zeroed before the kernel contribute exactly zero — so the
-# hand-tiled kernels run unchanged and the masked dispatch stays on-backend.
+# Row-masked variants (multicolor SymGS colors): the unmasked kernel runs and
+# the mask selects its rows, so masked rows are exactly zero whatever x
+# holds. Masking the operand instead would make a masked copy of the matrix
+# per color; those copies do not depend on x, so XLA hoists them out of a
+# solver's loops and keeps every color's copy live at once (several GB at
+# HPCG's 104³).
 
 
 @register_masked_spmv("dia", "pallas", supports=_dia_ok, needs_policy=True)
 def dia_masked_spmv_pallas(A: DIA, x, row_mask, policy):
-    if pallas_strategy(A, policy) == "resident":
-        return dia_spmv(A.offsets, jnp.where(row_mask[None, :], A.data, 0), x,
-                        extent=_dia_extent(A))
-    # tiled windows live in column coordinates, so rows can't be zeroed on
-    # the operand; mask the accumulated y instead (same contract, on-backend)
-    offs_t, dat_w = A.plan.arrays
-    y = dia_spmv_tiled(offs_t, dat_w, x, nrows=A.shape[0], col_tile=A.plan.ct)
-    return jnp.where(row_mask, y, 0)
+    return jnp.where(row_mask, dia_spmv_pallas(A, x, policy), 0)
 
 
 @register_masked_spmv("ell", "pallas", supports=_ell_ok, needs_policy=True)
 def ell_masked_spmv_pallas(A: ELL, x, row_mask, policy):
-    if pallas_strategy(A, policy) == "resident":
-        return ell_spmv(A.indices, jnp.where(row_mask[:, None], A.data, 0), x)
-    idx_t, dat_t = A.plan.arrays
-    return ell_spmv_tiled(idx_t, jnp.where(row_mask[None, :, None], dat_t, 0),
-                          x, col_tile=A.plan.ct)
+    return jnp.where(row_mask, ell_spmv_pallas(A, x, policy), 0)
 
 
 @register_spmm("bsr", "pallas", supports=_precision_ok)

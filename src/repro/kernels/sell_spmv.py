@@ -7,17 +7,20 @@ position) with almost no padding. This kernel runs that layout directly:
 
   - the grid walks blocks of ``jb`` j-steps; each block's (jb, C) index/data
     panels are dense (``core.tiling.build_scs_plan`` pads per bucket);
-  - scalar-prefetched ``btile``/``bwin`` arrays steer the *block specs*: which
-    (ct,) column tile of x the block gathers from, and which (sw, C) window
-    of the permuted output it accumulates into — the PrefetchScalarGridSpec
-    mechanism ``dia_spmv`` already uses, applied to both sides;
-  - same-window products are combined on the MXU with a (jb, sw) one-hot
-    local-slice contraction (the COO kernel's ``svcmpeq`` translation, at
-    slice rather than row granularity);
+  - ``btile`` names the column tile each block's tile-local indices point
+    into; the x gather ``x[btile * ct + idx]`` runs in XLA ahead of the
+    kernel (Mosaic has no gather from an arbitrary-length vector), so every
+    block arrives with its (jb, C) panel of x values;
+  - the scalar-prefetched ``bwin`` steers the output *block spec*: which
+    (sw, C) window of the permuted output the block accumulates into — the
+    PrefetchScalarGridSpec mechanism ``dia_spmv`` uses for its offsets;
+  - same-window products are combined with a local-slice compare per window
+    slice (the COO kernel's ``svcmpeq`` translation, at slice rather than row
+    granularity), reduced exactly in f32 on the VPU;
   - blocks are window-major, column-tile-minor, so output windows see
     contiguous runs: "window changed" initialises, otherwise accumulate.
-    Column tiling therefore costs nothing extra here — a resident matrix is
-    simply the ``ntiles == 1`` special case of the same kernel.
+    A resident matrix is simply the ``ntiles == 1`` case of the same
+    kernel.
 
 ``csr``×``pallas`` dispatches through this kernel via the ``"scs"``
 KernelPlan cached on the CSR container at convert time (its SELL-C-σ view),
@@ -32,37 +35,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import interpret_mode
 
-def _kernel(btile_ref, bwin_ref, lsl_ref, x_ref, idx_ref, dat_ref, y_ref,
-            *, jb: int, sw: int, C: int):
+
+def _sum_rows(v):
+    """(n, C) -> (1, C) by pairwise halving: a fixed association order, so a
+    batched (vmapped) call rounds exactly like a single one — a plain
+    ``sum(axis=0)`` leaves the order to the compiler, which picks it per
+    program."""
+    while v.shape[0] > 1:
+        h = v.shape[0] // 2
+        head = v[:h] + v[h:2 * h]
+        v = jnp.concatenate([head, v[2 * h:]], axis=0) if v.shape[0] % 2 else head
+    return v
+
+
+def _kernel(bwin_ref, lsl_ref, xg_ref, dat_ref, y_ref, *, sw: int):
     b = pl.program_id(0)
-    idx = idx_ref[...]            # (jb, C) tile-local columns, -1 = padding
-    dat = dat_ref[...]
-    lsl = lsl_ref[...]            # (jb,) window-local slice of each j-step
-    valid = idx >= 0
-    x = x_ref[...]                # this block's (ct,) x tile
-    gathered = jnp.take(x, jnp.where(valid, idx, 0).astype(jnp.int32), axis=0)
-    prod = jnp.where(valid, dat.astype(jnp.float32) * gathered.astype(jnp.float32),
-                     0.0)         # (jb, C)
-    onehot = (lsl[:, None] == jax.lax.broadcasted_iota(jnp.int32, (jb, sw), 1))
-    contrib = jnp.einsum("js,jc->sc", onehot.astype(jnp.float32), prod)  # (sw, C)
+    prod = dat_ref[...] * xg_ref[...]   # (jb, C); padding lanes carry x = 0
+    lsl = lsl_ref[...]                  # (jb, 1) window-local slice per j-step
+    contrib = jnp.concatenate(
+        [_sum_rows(jnp.where(lsl == s, prod, 0.0)) for s in range(sw)],
+        axis=0)                         # (sw, C)
 
     prev = bwin_ref[jnp.maximum(b - 1, 0)]
     fresh = (b == 0) | (prev != bwin_ref[b])
 
     @pl.when(fresh)
     def _init():
-        y_ref[...] = contrib.astype(y_ref.dtype)
+        y_ref[...] = contrib
 
     @pl.when(jnp.logical_not(fresh))
     def _acc():
-        y_ref[...] += contrib.astype(y_ref.dtype)
+        y_ref[...] += contrib
 
 
-@functools.partial(jax.jit, static_argnames=("nrows", "col_tile", "ntiles",
-                                             "C", "sw", "jb", "nwin", "interpret"))
+@functools.partial(jax.jit, static_argnames=("nrows", "col_tile", "C", "sw",
+                                             "jb", "nwin", "interpret"))
 def scs_spmv(btile, bwin, lsl, idx2, dat2, perm, x, *, nrows: int,
-             col_tile: int, ntiles: int, C: int, sw: int, jb: int, nwin: int,
+             col_tile: int, C: int, sw: int, jb: int, nwin: int,
              interpret: bool | None = None) -> jnp.ndarray:
     """y = A @ x over a ``build_scs_plan`` SELL-C-σ stream.
 
@@ -75,27 +86,27 @@ def scs_spmv(btile, bwin, lsl, idx2, dat2, perm, x, *, nrows: int,
 
     Returns (nrows,) in original row order.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nblocks = btile.shape[0]
-    x_pad = jnp.zeros((ntiles * col_tile,), x.dtype).at[: x.shape[0]].set(x)
+    valid = idx2 >= 0
+    gcol = (jnp.repeat(btile, jb)[:, None] * col_tile
+            + jnp.where(valid, idx2.astype(jnp.int32), 0))
+    xg = jnp.where(valid, x[jnp.minimum(gcol, x.shape[0] - 1)].astype(jnp.float32), 0.0)
 
     y2 = pl.pallas_call(
-        functools.partial(_kernel, jb=jb, sw=sw, C=C),
+        functools.partial(_kernel, sw=sw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=1,
             grid=(nblocks,),
             in_specs=[
-                pl.BlockSpec((jb,), lambda b, bt, bw: (b,)),
-                pl.BlockSpec((col_tile,), lambda b, bt, bw: (bt[b],)),
-                pl.BlockSpec((jb, C), lambda b, bt, bw: (b, 0)),
-                pl.BlockSpec((jb, C), lambda b, bt, bw: (b, 0)),
+                pl.BlockSpec((jb, 1), lambda b, bw: (b, 0)),
+                pl.BlockSpec((jb, C), lambda b, bw: (b, 0)),
+                pl.BlockSpec((jb, C), lambda b, bw: (b, 0)),
             ],
-            out_specs=pl.BlockSpec((sw, C), lambda b, bt, bw: (bw[b], 0)),
+            out_specs=pl.BlockSpec((None, sw, C), lambda b, bw: (bw[b], 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((nwin * sw, C), jnp.float32),
-        interpret=interpret,
-    )(btile, bwin, lsl, x_pad, idx2, dat2)
+        out_shape=jax.ShapeDtypeStruct((nwin, sw, C), jnp.float32),
+        interpret=interpret_mode(interpret),
+    )(bwin, lsl.reshape(-1, 1), xg, dat2.astype(jnp.float32))
 
     # un-permute: y2.reshape(-1)[p] is the σ-sorted row at position p
     yp = y2.reshape(-1)[: perm.shape[0]]
@@ -106,7 +117,7 @@ def scs_spmv(btile, bwin, lsl, idx2, dat2, perm, x, *, nrows: int,
 def scs_spmv_from_plan(plan, x, nrows: int, interpret: bool | None = None):
     """Dispatch-table adapter: run :func:`scs_spmv` from a ``"scs"`` plan."""
     btile, bwin, lsl, idx2, dat2, perm = plan.arrays
-    ct, ntiles, C, sw, jb, nwin = (int(v) for v in plan.meta)
+    ct, _, C, sw, jb, nwin = (int(v) for v in plan.meta)
     return scs_spmv(btile, bwin, lsl, idx2, dat2, perm, x, nrows=nrows,
-                    col_tile=ct, ntiles=ntiles, C=C, sw=sw, jb=jb, nwin=nwin,
+                    col_tile=ct, C=C, sw=sw, jb=jb, nwin=nwin,
                     interpret=interpret)

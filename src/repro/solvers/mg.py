@@ -15,8 +15,10 @@ therefore jittable end-to-end and retargets with the dispatch table.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
@@ -44,6 +46,8 @@ def injection_operators(nx: int, ny: int, nz: int,
     return as_operator(R, "coo", dtype=dtype), as_operator(P, "coo", dtype=dtype)
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["A", "smoother", "R", "P"], meta_fields=["grid"])
 @dataclass(frozen=True)
 class MGLevel:
     grid: Tuple[int, int, int]
@@ -59,11 +63,15 @@ class MGLevel:
         return f"{self.A.format}/{backend}"
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["levels"],
+         meta_fields=["pre", "post", "coarse_sweeps"])
 @dataclass(frozen=True)
 class VCycle:
     """Recursive V-cycle, ``__call__(r) ~= A^-1 r`` — a symmetric
     positive-definite preconditioner when pre == post (SymGS is symmetric and
-    P = R^T), so it drops straight into preconditioned CG."""
+    P = R^T), so it drops straight into preconditioned CG. A pytree: pass it
+    to a jitted solver as an argument, so its arrays are not baked into the
+    executable as constants."""
 
     levels: Tuple[MGLevel, ...]
     pre: int = 1
@@ -78,7 +86,8 @@ class VCycle:
         return " | ".join(f"{'x'.join(map(str, l.grid))}:{l.chosen}"
                           for l in self.levels)
 
-    def retuned(self, candidates=None, mode: str = "run") -> "VCycle":
+    def retuned(self, candidates=None, mode: str = "run",
+                finest: Optional[SparseOperator] = None) -> "VCycle":
         """Retarget every level's operators to a fresh (format, backend)
         choice — the per-level format choice of Table III. Schedules
         (coloring, diag, R/P) are reused; only the SpMV operators change.
@@ -87,12 +96,17 @@ class VCycle:
         ``mode="predict"`` uses the zero-run feature selector instead
         (``SparseOperator.tune(mode="predict")``) — no kernel executes
         during setup, which is the cheap path deep hierarchies want.
+        ``finest`` is an operator already tuned for the finest level's
+        matrix (the solver's own, in HPCG): it is installed there instead
+        of racing that level a second time.
         """
         if mode not in ("run", "predict"):
             raise ValueError(f"retuned mode {mode!r}: expected 'run' or 'predict'")
         levels = []
-        for l in self.levels:
-            if mode == "predict":
+        for li, l in enumerate(self.levels):
+            if li == 0 and finest is not None:
+                op = finest
+            elif mode == "predict":
                 op = l.A.tune(candidates=candidates, mode="predict")
             else:
                 op = autotune_spmv(l.A, candidates=candidates).operator
@@ -159,7 +173,7 @@ def distributable_depth(nx: int, ny: int, nz: int, nparts: int,
 
 def distribute_vcycle(vc: VCycle, mesh, axis: str = "data", *,
                       tune: bool = False, candidates=None,
-                      dtype=jnp.float32) -> VCycle:
+                      dtype=jnp.float32, fmt: str = "csr") -> VCycle:
     """The V-cycle with every level's linear algebra sharded over ``mesh``.
 
     Per level (the tentpole wiring of the distributed HPCG):
@@ -180,9 +194,10 @@ def distribute_vcycle(vc: VCycle, mesh, axis: str = "data", *,
             :func:`distributable_depth`).
         mesh / axis: 1-D device axis to shard over.
         tune: per-partition run-first tune of each level's operator
-            (Table III per-process choices), otherwise csr/plain.
+            (Table III per-process choices), otherwise ``fmt``/plain.
         candidates: candidate ``DispatchKey``s when tuning.
         dtype: container value dtype.
+        fmt: local and remote format of the untuned level operators.
 
     Returns:
         A ``VCycle`` whose ``__call__`` maps sharded residuals to sharded
@@ -199,8 +214,8 @@ def distribute_vcycle(vc: VCycle, mesh, axis: str = "data", *,
             raise ValueError(
                 f"level {l.grid} has {s.shape[0]} rows, not divisible by "
                 f"{nparts} parts — clamp depth with distributable_depth()")
-        A_d = DistributedOperator.build(s, mesh, axis, local="csr",
-                                        remote="csr", mode="auto", dtype=dtype)
+        A_d = DistributedOperator.build(s, mesh, axis, local=fmt,
+                                        remote=fmt, mode="auto", dtype=dtype)
         if tune:
             A_d = A_d.tune(candidates)
         R_d = P_d = None

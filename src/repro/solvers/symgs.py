@@ -18,6 +18,7 @@ Morpheus abstraction, now covering HPCG's dominant non-SpMV phase.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -69,6 +70,9 @@ def _padded_offdiag(s: sp.csr_matrix) -> Tuple[np.ndarray, np.ndarray]:
     return idx, val
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["A", "diag", "masks", "off_idx", "off_val"],
+         meta_fields=["method"])
 @dataclass(frozen=True)
 class SymGS:
     """One symmetric Gauss-Seidel sweep, ``__call__`` = apply M^-1 from zero.
@@ -76,6 +80,7 @@ class SymGS:
     ``A`` drives the multicolor path (masked SpMV per color through the
     dispatch table); ``diag``/``masks`` are host-built schedule data. The
     reference path carries the padded off-diagonal triangle arrays instead.
+    A pytree, so a jitted solver can take it as an argument.
     """
 
     A: SparseOperator

@@ -30,6 +30,63 @@ def test_autotuner_structural_guards():
     assert "dia" in skipped2
 
 
+def test_structural_skip_bounds_the_dense_candidate():
+    """Densifying is refused once the f32 n x m array passes the byte bound
+    (HPCG's 104³ operator would need ~5 TB) and allowed below it."""
+    import scipy.sparse as sp
+    from repro.core.autotune import structural_skip
+    from repro.core.select import DENSE_MAX_BYTES
+
+    small = M.fdm27(4, 4, 4)
+    assert structural_skip(small, "dense") is None
+    side = int((DENSE_MAX_BYTES // 4) ** 0.5) + 1
+    why = structural_skip(sp.eye(side, format="csr"), "dense")
+    assert why is not None and why.startswith("dense=")
+    assert structural_skip(small, "dense", dense_max_bytes=4 * 64 * 64 - 1)
+
+
+def test_select_infeasible_agrees_on_the_dense_bound():
+    """Pruning and racing refuse the same dense candidates."""
+    import scipy.sparse as sp
+    from repro.core import select
+    from repro.core.autotune import structural_skip
+    from repro.core.features import extract_features
+
+    side = int((select.DENSE_MAX_BYTES // 4) ** 0.5) + 1
+    for s in (M.fdm27(4, 4, 4), sp.eye(side, format="csr")):
+        feats = extract_features(s)
+        assert ((select.infeasible(feats, "dense") is None)
+                == (structural_skip(s, "dense") is None))
+
+
+def test_autotune_fails_when_a_candidate_raises(chain_failure_injector,
+                                               fresh_health):
+    """A candidate kernel that raises fails the tune: it is neither skipped
+    nor timed as the plain backend under its own label."""
+    from repro.core import DispatchKey
+    from repro.core.errors import KernelExecutionError
+
+    bad = DispatchKey("csr", "pallas")
+    chain_failure_injector["fail"].add(bad)
+    with pytest.raises(KernelExecutionError):
+        autotune_spmv(M.banded(64, 2, seed=0), iters=1, warmup=1,
+                      candidates=[DispatchKey("csr", "plain"), bad])
+    attempts = chain_failure_injector["attempts"]
+    assert attempts[-1] == bad  # nothing ran in its place after it failed
+
+
+def test_autotune_skips_a_refused_backend():
+    """A backend whose capability predicate refuses the container is skipped
+    as unsupported; before strict racing it was timed as plain."""
+    from repro.core import ExecutionPolicy
+
+    res = autotune_spmv(M.banded(64, 2, seed=0), iters=1, warmup=1,
+                        candidates=[("csr", "plain"), ("csr", "pallas")],
+                        policy=ExecutionPolicy(accum_dtype="float64"))
+    assert ("csr", "pallas") not in res.table
+    assert ("csr", "pallas", "unsupported") in res.skipped
+
+
 @pytest.mark.slow
 def test_autotuner_prefers_dia_family_for_banded():
     """Fig 3 takeaway: structured/banded matrices leave the CSR default.

@@ -88,6 +88,43 @@ def test_split_local_remote_reassembles(nparts, halo):
         _reassemble(locals_, remotes, h, s.shape, nparts), s.toarray())
 
 
+def _split_remote_lil(s, nparts, halo):
+    """The LIL column-zeroing remote split the entry-wise one replaced."""
+    import scipy.sparse as sp
+
+    s = s.tocsr()
+    m = s.shape[0] // nparts
+    out = []
+    for p in range(nparts):
+        rem = s[p * m:(p + 1) * m].tolil(copy=True)
+        rem[:, p * m:(p + 1) * m] = 0
+        rem = rem.tocsr()
+        rem.eliminate_zeros()
+        if halo is not None:
+            win = sp.lil_matrix((m, m + 2 * halo), dtype=s.dtype)
+            rc = rem.tocoo()
+            win[rc.row, rc.col - (p * m - halo)] = rc.data
+            rem = win.tocsr()
+        out.append(rem)
+    return out
+
+
+@pytest.mark.parametrize("halo", ["auto", None])
+def test_split_local_remote_matches_lil_reference(halo):
+    """Same remote matrices, entry for entry and in order, as the LIL split
+    (explicit zeros dropped from the remote part in both)."""
+    s = M.banded(48, 5, seed=3).tocsr()
+    row11 = slice(s.indptr[11], s.indptr[12])
+    s.data[row11][s.indices[row11] == 13] = 0.0  # stored zero, remote column
+    assert s.nnz == M.banded(48, 5, seed=3).nnz
+    _, remotes, h = split_local_remote(s, 4, halo=halo)
+    for got, want in zip(remotes, _split_remote_lil(s, 4, h)):
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(got.indptr, want.indptr)
+        np.testing.assert_array_equal(got.indices, want.indices)
+        np.testing.assert_array_equal(got.data, want.data)
+
+
 def test_split_local_remote_halo_covers_banded_reach():
     """A bandwidth-3 matrix needs exactly halo=3 window columns."""
     s = M.banded(24, 3, seed=1)
@@ -168,6 +205,27 @@ def test_rowblock_operator_refuses_tune():
                                    local="csr", mode="rowblock")
     with pytest.raises(ValueError, match="rowblock"):
         op.tune()
+
+
+def test_distributed_operator_is_a_pytree():
+    """Flattened and rebuilt, the operator keeps its layout and applies
+    identically, as a jit argument too; the host-side source is dropped."""
+    import jax
+    from jax.sharding import Mesh
+    from repro.distributed_op import DistributedOperator
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    s = M.fdm27(4, 4, 4)
+    op = DistributedOperator.build(s, mesh, "data", local="csr", remote="coo")
+    leaves, tree = jax.tree.flatten(op)
+    op2 = jax.tree.unflatten(tree, leaves)
+    assert op2.describe() == op.describe() and op2.halo == op.halo
+    assert op2.source is None
+    x = op.device_put(np.random.default_rng(0).standard_normal(s.shape[1]))
+    y = np.asarray(op @ x)
+    np.testing.assert_array_equal(np.asarray(op2 @ x), y)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda A, x: A @ x)(op, x)), y)
 
 
 # ------------------------------------- DistributedOperator (4 fake devices) --
@@ -259,6 +317,21 @@ assert res.rel_res <= 1e-6, res.rel_res
 assert res.valid, (res.rel_err, res.rel_res)
 assert res.pcg_iters <= 25, res.pcg_iters
 print("OK", res.pcg_iters, res.rel_res)
+"""
+    assert "OK" in run_py(code, devices=4, timeout=560)
+
+
+def test_hpcg_distributed_plain_dia_reference():
+    """``reference="dia"``: the single-device oracle, the reference split and
+    the untuned distributed hierarchy run plain DIA, and the rowblock DIA
+    SpMV is bit-for-bit the single-device one."""
+    code = """
+from repro.apps.hpcg import run_hpcg_distributed
+res = run_hpcg_distributed(None, 16, 16, 8, iters=50, timed=False,
+                           verbose=False, reference="dia")
+assert res.bitwise and res.valid, (res.bitwise, res.valid, res.rel_res)
+assert "dist(dia+dia)" in res.mg_levels, res.mg_levels
+print("OK")
 """
     assert "OK" in run_py(code, devices=4, timeout=560)
 

@@ -199,3 +199,58 @@ def test_full_hpcg_16cubed_acceptance():
     assert res.bitwise, "optimised pipeline drifted from reference on csr/plain"
     assert res.valid and res.rel_err < 1e-3, (res.valid, res.rel_err)
     assert res.mg_levels  # per-level choices were recorded
+
+
+@pytest.mark.parametrize("precond", [True, False])
+def test_hpcg_solvers_embed_no_matrix_data(precond):
+    """The jitted HPCG solvers take the operator and the hierarchy as
+    arguments: the lowered program does not grow with the matrix, as it
+    would if their arrays were baked in as constants."""
+    from repro.apps.hpcg import _solver_pair
+
+    def lowered_text(g):
+        A = as_operator(M.fdm27(g, g, g), "csr").using("plain")
+        mg = build_mg(g, g, g, depth=2, fmt="csr") if precond else None
+        b = jnp.ones((g ** 3,), jnp.float32)
+        _, conv = _solver_pair(A, mg, 5, 1e-6)
+        n_leaves = len(jax.tree.leaves((A, mg, b)))
+        lowered = conv.func.lower(*conv.args, b)
+        assert len(jax.tree.leaves(lowered.args_info)) == n_leaves
+        return lowered.as_text()
+
+    small, large = lowered_text(8), lowered_text(16)
+    assert len(large) < 1.05 * len(small), (len(small), len(large))
+
+
+def test_vcycle_round_trips_as_a_pytree():
+    """A hierarchy flattened and rebuilt applies bit-identically, under jit
+    as an argument as well as eagerly."""
+    mg = build_mg(8, 8, 8, depth=2, fmt="csr")
+    leaves, tree = jax.tree.flatten(mg)
+    mg2 = jax.tree.unflatten(tree, leaves)
+    assert mg2.describe() == mg.describe() and mg2.pre == mg.pre
+    r = jnp.asarray(np.random.default_rng(0).standard_normal(512), jnp.float32)
+    y = np.asarray(mg(r))
+    np.testing.assert_array_equal(np.asarray(mg2(r)), y)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda m, r: m(r))(mg, r)), y)
+
+
+def test_hpcg_with_a_plain_dia_reference():
+    """``reference="dia"``: the reference solve, the replay and the
+    untuned hierarchy run plain DIA, and the replay is still bit-for-bit."""
+    from repro.apps.hpcg import run_hpcg
+
+    res = run_hpcg(8, 8, 8, iters=50, verbose=False, timed=False, depth=2,
+                   candidates=FAST_CANDIDATES, reference="dia")
+    assert res.bitwise and res.valid, (res.bitwise, res.valid, res.rel_res)
+
+
+def test_retuned_installs_the_finest_operator():
+    """``finest=`` takes the place of the finest level's race; the coarser
+    levels are still tuned."""
+    mg = build_mg(8, 8, 8, depth=2, fmt="csr")
+    op = as_operator(M.fdm27(8, 8, 8), "dia").using("plain")
+    tuned = mg.retuned((DispatchKey("ell", "plain"),), finest=op)
+    assert tuned.levels[0].A is op and tuned.levels[0].smoother.A is op
+    assert tuned.levels[1].A.format == "ell"

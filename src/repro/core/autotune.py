@@ -7,6 +7,11 @@ deliberately measurement-based (not a learned oracle — that is the
 Morpheus-Oracle follow-up paper [35]); conversion cost is excluded, matching
 the paper's methodology of timing 100 SpMV iterations after setup.
 
+A race records the host spans ``tune.race`` and, per candidate,
+``tune.candidate`` (its conversion's ``convert``, ``tune.first_call`` for
+the first warm-up call with its trace and compile, ``tune.time`` for the
+other calls) and the counter ``tune.calls`` (``repro.core.obs``).
+
 The result carries a ready-to-use ``SparseOperator`` (winning container +
 policy preferring the winning backend) — the operator-centric entry point is
 ``SparseOperator.tune()`` / ``TuneResult.operator``.
@@ -20,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from . import obs
 from .convert import col_tile_for_policy as _col_tile_for_policy
 from .convert import container_to_scipy as _container_to_scipy
 from .convert import from_dense as _from_dense
@@ -68,13 +74,18 @@ class TuneResult:
 
 
 def _time_call(fn, *args, iters: int = 10, warmup: int = 3) -> float:
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
+    obs.count("tune.calls", warmup + iters)
+    with obs.span("tune.first_call"):
+        if warmup:
+            jax.block_until_ready(fn(*args))
     ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter_ns()
-        jax.block_until_ready(fn(*args))
-        ts.append(time.perf_counter_ns() - t0)
+    with obs.span("tune.time"):
+        for _ in range(warmup - 1):
+            jax.block_until_ready(fn(*args))
+        for _ in range(iters):
+            t0 = time.perf_counter_ns()
+            jax.block_until_ready(fn(*args))
+            ts.append(time.perf_counter_ns() - t0)
     return float(np.median(ts)) / 1e3  # us
 
 
@@ -183,80 +194,82 @@ def autotune_spmv(
     """
     import scipy.sparse as sp
 
-    if isinstance(a_dense, SparseOperator):
-        a_dense = a_dense.container
-    if hasattr(a_dense, "to_dense") and not sp.issparse(a_dense):
-        a_dense = _container_to_scipy(a_dense)
-    s = a_dense if sp.issparse(a_dense) else sp.csr_matrix(np.asarray(a_dense))
-    s = s.tocsr()
-    n = s.shape[1]
-    x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
-    x = jax.device_put(x)
+    with obs.span("tune.race"):
+        if isinstance(a_dense, SparseOperator):
+            a_dense = a_dense.container
+        if hasattr(a_dense, "to_dense") and not sp.issparse(a_dense):
+            a_dense = _container_to_scipy(a_dense)
+        s = a_dense if sp.issparse(a_dense) else sp.csr_matrix(np.asarray(a_dense))
+        s = s.tocsr()
+        n = s.shape[1]
+        x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
+        x = jax.device_put(x)
 
-    table: Dict[Tuple[str, str], float] = {}
-    skipped: List[Tuple[str, str, str]] = []
-    mats = {}
-    skip_cache: Dict[str, Optional[str]] = {}  # structure stats once per fmt
-    cand = _normalize_candidates(candidates if candidates is not None else DEFAULT_CANDIDATES)
-    if prune:
-        from . import select
-        from .features import extract_features
+        table: Dict[Tuple[str, str], float] = {}
+        skipped: List[Tuple[str, str, str]] = []
+        mats = {}
+        skip_cache: Dict[str, Optional[str]] = {}  # structure stats once per fmt
+        cand = _normalize_candidates(candidates if candidates is not None else DEFAULT_CANDIDATES)
+        if prune:
+            from . import select
+            from .features import extract_features
 
-        feats = extract_features(s)
-        keep = {(k.format, k.backend) for k in select.prune_candidates(
-            feats, int(prune),
-            policy=policy if policy is not None else DEFAULT_POLICY,
-            candidates=cand, dia_max_diags=dia_max_diags,
-            ell_max_width_factor=ell_max_width_factor)}
-        pruned_cand = []
+            feats = extract_features(s)
+            keep = {(k.format, k.backend) for k in select.prune_candidates(
+                feats, int(prune),
+                policy=policy if policy is not None else DEFAULT_POLICY,
+                candidates=cand, dia_max_diags=dia_max_diags,
+                ell_max_width_factor=ell_max_width_factor)}
+            pruned_cand = []
+            for fmt, impl in cand:
+                # structurally infeasible keys stay in the loop so they are
+                # skipped with their *structural* reason, not blamed on the
+                # selector (the model only prunes feasible-but-predicted-slow)
+                if (fmt, impl) in keep or select.infeasible(
+                        feats, fmt, dia_max_diags, ell_max_width_factor) is not None:
+                    pruned_cand.append((fmt, impl))
+                else:
+                    skipped.append((fmt, impl, "pruned by selector"))
+            cand = tuple(pruned_cand)
         for fmt, impl in cand:
-            # structurally infeasible keys stay in the loop so they are
-            # skipped with their *structural* reason, not blamed on the
-            # selector (the model only prunes feasible-but-predicted-slow)
-            if (fmt, impl) in keep or select.infeasible(
-                    feats, fmt, dia_max_diags, ell_max_width_factor) is not None:
-                pruned_cand.append((fmt, impl))
-            else:
-                skipped.append((fmt, impl, "pruned by selector"))
-        cand = tuple(pruned_cand)
-    for fmt, impl in cand:
-        if fmt not in skip_cache:
-            skip_cache[fmt] = structural_skip(s, fmt, dia_max_diags,
-                                              ell_max_width_factor)
-        why = skip_cache[fmt]
-        if why is not None:
-            skipped.append((fmt, impl, why))
-            continue
-        if impl not in available_impls(fmt):
-            skipped.append((fmt, impl, "impl not registered"))
-            continue
-        if fmt not in mats:
-            kw = {"dtype": dtype} if dtype is not None else {}
-            if fmt in _COL_TILED_FORMATS:
-                # candidates are measured under the caller's VMEM budget:
-                # large-n matrices get the matching column-tile plan built
-                # in, resident-under-this-policy ones skip it (or keep the
-                # single-tile SCS layout csr/sell always need)
-                base = policy if policy is not None else DEFAULT_POLICY
-                kw["col_tile"] = _col_tile_for_policy(fmt, n, base.col_tile(n))
-            mats[fmt] = _from_dense(s, fmt, **kw)
-        A = mats[fmt]
-        pol = (policy if policy is not None else DEFAULT_POLICY).replace(
-            backends=(impl,), allow_fallback=False)
-        fn = jax.jit(lambda A, x, pol=pol: spmv(A, x, policy=pol))
-        try:
-            if time_fn is not None:
-                table[(fmt, impl)] = time_fn(fn, A, x, DispatchKey(fmt, impl),
-                                             iters=iters, warmup=warmup)
-            else:
-                table[(fmt, impl)] = _time_call(fn, A, x, iters=iters, warmup=warmup)
-        except BackendUnsupportedError:
-            skipped.append((fmt, impl, "unsupported"))
+            if fmt not in skip_cache:
+                skip_cache[fmt] = structural_skip(s, fmt, dia_max_diags,
+                                                  ell_max_width_factor)
+            why = skip_cache[fmt]
+            if why is not None:
+                skipped.append((fmt, impl, why))
+                continue
+            if impl not in available_impls(fmt):
+                skipped.append((fmt, impl, "impl not registered"))
+                continue
+            with obs.span("tune.candidate", fmt=fmt, impl=impl):
+                if fmt not in mats:
+                    kw = {"dtype": dtype} if dtype is not None else {}
+                    if fmt in _COL_TILED_FORMATS:
+                        # candidates are measured under the caller's VMEM budget:
+                        # large-n matrices get the matching column-tile plan built
+                        # in, resident-under-this-policy ones skip it (or keep the
+                        # single-tile SCS layout csr/sell always need)
+                        base = policy if policy is not None else DEFAULT_POLICY
+                        kw["col_tile"] = _col_tile_for_policy(fmt, n, base.col_tile(n))
+                    mats[fmt] = _from_dense(s, fmt, **kw)
+                A = mats[fmt]
+                pol = (policy if policy is not None else DEFAULT_POLICY).replace(
+                    backends=(impl,), allow_fallback=False)
+                fn = jax.jit(lambda A, x, pol=pol: spmv(A, x, policy=pol))
+                try:
+                    if time_fn is not None:
+                        table[(fmt, impl)] = time_fn(fn, A, x, DispatchKey(fmt, impl),
+                                                     iters=iters, warmup=warmup)
+                    else:
+                        table[(fmt, impl)] = _time_call(fn, A, x, iters=iters, warmup=warmup)
+                except BackendUnsupportedError:
+                    skipped.append((fmt, impl, "unsupported"))
 
-    if not table:
-        raise RuntimeError("auto-tuner: no candidate succeeded")
-    (fmt, impl), t = min(table.items(), key=lambda kv: kv[1])
-    return TuneResult(fmt, impl, t, mats[fmt], table, skipped, base_policy=policy)
+        if not table:
+            raise RuntimeError("auto-tuner: no candidate succeeded")
+        (fmt, impl), t = min(table.items(), key=lambda kv: kv[1])
+        return TuneResult(fmt, impl, t, mats[fmt], table, skipped, base_policy=policy)
 
 
 def optimal_format_distribution(suite, candidates=None, **kw) -> Dict[str, str]:

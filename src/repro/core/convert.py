@@ -4,16 +4,22 @@ Conversions are host-side (numpy/scipy) — they play the role of
 ``armpl_spmat_create_* + armpl_spmv_optimize``: a one-time setup cost that the
 registry caches behind a handle (see ``registry.py``), after which the
 device-side SpMV runs on the converted container.
+
+Every build goes through :func:`from_dense` or :func:`convert`, each of
+which records the host span ``convert`` (attributes ``fmt``, ``nnz``,
+``bytes``) and the counters ``convert.calls`` and ``convert.bytes``
+(``repro.core.obs``); a conversion inside another counts once.
 """
 from __future__ import annotations
 
 from typing import Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 
-from . import tiling
+from . import obs, tiling
 from .formats import BSR, COO, CSR, DIA, ELL, SELL, Dense
 
 #: ``col_tile`` convert argument: ``None`` = auto (tile only when the column
@@ -66,13 +72,25 @@ def _as_scipy_sorted(a) -> sp.csr_matrix:
     return s
 
 
+def _recorded(span, out):
+    """Count a finished conversion once, at the outermost ``convert`` span."""
+    if span and not span.nested:
+        nbytes = sum(getattr(x, "nbytes", 0)
+                     for x in jax.tree_util.tree_leaves(out))
+        span.set(nnz=out.nnz, bytes=nbytes)
+        obs.count("convert.calls")
+        obs.count("convert.bytes", nbytes)
+    return out
+
+
 def from_dense(a, fmt: str, dtype=jnp.float32, **kw):
     """Build a sparse container of format ``fmt`` from a dense/scipy matrix."""
     builders = {
         "coo": to_coo, "csr": to_csr, "dia": to_dia, "ell": to_ell,
         "sell": to_sell, "bsr": to_bsr, "dense": to_densefmt,
     }
-    return builders[fmt](a, dtype=dtype, **kw)
+    with obs.span("convert", fmt=fmt) as s:
+        return _recorded(s, builders[fmt](a, dtype=dtype, **kw))
 
 
 def _padded_triplets(c):
@@ -149,7 +167,9 @@ def convert(A, fmt: str, **kw):
                 "bsr": lambda: {"bs": A.bs, "bwidth": A.bwidth}}.get(fmt)
         if keep is not None:
             kw = {**keep(), **kw}
-    return from_dense(container_to_scipy(A), fmt, dtype=A.dtype, **kw)
+    with obs.span("convert", fmt=fmt) as s:
+        return _recorded(s, from_dense(container_to_scipy(A), fmt,
+                                       dtype=A.dtype, **kw))
 
 
 def to_densefmt(a, dtype=jnp.float32):

@@ -12,6 +12,8 @@ from typing import Dict, Iterator, Tuple
 import numpy as np
 import scipy.sparse as sp
 
+from . import obs
+
 
 def banded(n: int, band: int = 3, seed: int = 0,
            dtype=np.float64) -> sp.csr_matrix:
@@ -30,25 +32,28 @@ def tridiag(n: int, seed: int = 0, dtype=np.float64) -> sp.csr_matrix:
 def fdm27(nx: int, ny: int, nz: int, dtype=np.float64) -> sp.csr_matrix:
     """HPCG's 27-point stencil on an nx*ny*nz grid: 26 on the diagonal,
     -1 for each of the up-to-26 neighbours (Dirichlet-style truncation).
-    Built vectorised so multigrid hierarchies over large grids are cheap."""
-    n = nx * ny * nz
-    k, j, i = np.meshgrid(np.arange(nz), np.arange(ny), np.arange(nx), indexing="ij")
-    i, j, k = i.ravel(), j.ravel(), k.ravel()
-    r = i + nx * (j + ny * k)
-    rows, cols, vals = [], [], []
-    for dk in (-1, 0, 1):
-        for dj in (-1, 0, 1):
-            for di in (-1, 0, 1):
-                ii, jj, kk = i + di, j + dj, k + dk
-                ok = ((ii >= 0) & (ii < nx) & (jj >= 0) & (jj < ny)
-                      & (kk >= 0) & (kk < nz))
-                rows.append(r[ok])
-                cols.append((ii + nx * (jj + ny * kk))[ok])
-                vals.append(np.full(int(ok.sum()),
-                                    26.0 if (di, dj, dk) == (0, 0, 0) else -1.0))
-    return sp.csr_matrix((np.concatenate(vals),
-                          (np.concatenate(rows), np.concatenate(cols))),
-                         shape=(n, n)).astype(dtype, copy=False)
+    Built vectorised so multigrid hierarchies over large grids are cheap.
+    Recorded as the host span ``matrix`` (``repro.core.obs``)."""
+    with obs.span("matrix", grid=(nx, ny, nz)):
+        n = nx * ny * nz
+        k, j, i = np.meshgrid(np.arange(nz), np.arange(ny), np.arange(nx),
+                              indexing="ij")
+        i, j, k = i.ravel(), j.ravel(), k.ravel()
+        r = i + nx * (j + ny * k)
+        rows, cols, vals = [], [], []
+        for dk in (-1, 0, 1):
+            for dj in (-1, 0, 1):
+                for di in (-1, 0, 1):
+                    ii, jj, kk = i + di, j + dj, k + dk
+                    ok = ((ii >= 0) & (ii < nx) & (jj >= 0) & (jj < ny)
+                          & (kk >= 0) & (kk < nz))
+                    rows.append(r[ok])
+                    cols.append((ii + nx * (jj + ny * kk))[ok])
+                    vals.append(np.full(int(ok.sum()), 26.0 if (di, dj, dk)
+                                        == (0, 0, 0) else -1.0))
+        return sp.csr_matrix((np.concatenate(vals),
+                              (np.concatenate(rows), np.concatenate(cols))),
+                             shape=(n, n)).astype(dtype, copy=False)
 
 
 def coarsen_injection(nx: int, ny: int, nz: int) -> np.ndarray:

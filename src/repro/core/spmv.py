@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from . import health as _health
+from . import obs as _obs
 from .errors import BackendUnsupportedError, KernelExecutionError, _all_finite
 from .formats import BSR, COO, CSR, DIA, ELL, SELL, Dense
 from .operator import ExecutionPolicy, current_policy, policy_for_impl
@@ -235,11 +236,13 @@ def select_spmv(A, policy: ExecutionPolicy) -> KernelEntry:
 
 
 def _run_chain(steps: List[Tuple[DispatchKey, Callable]],
-               policy: ExecutionPolicy, opname: str):
+               policy: ExecutionPolicy, opname: str, scope: str):
     """Execute the first step that completes; a step that raises (or returns
     non-finite output under ``check_finite``) records a failure against its
     key and control falls to the next step. The last step's failure is
-    wrapped in ``KernelExecutionError`` — by then the chain is exhausted."""
+    wrapped in ``KernelExecutionError`` — by then the chain is exhausted.
+    Each step traces under the device scope ``<scope>/<format>/<backend>``,
+    so the ops of the lane that ran carry its name."""
     reg = _health.registry()
     plan = _health._FAULT_PLAN
     last_exc: Optional[Exception] = None
@@ -248,7 +251,8 @@ def _run_chain(steps: List[Tuple[DispatchKey, Callable]],
         try:
             if plan is not None:
                 plan.fire("kernel", key)
-            y = thunk()
+            with _obs.scope(scope, key.format, key.backend):
+                y = thunk()
             if plan is not None:
                 y = plan.corrupt("nonfinite", key, y)
         except Exception as e:
@@ -277,7 +281,7 @@ def _run_chain(steps: List[Tuple[DispatchKey, Callable]],
 def _dispatch_spmv(A, x, policy: ExecutionPolicy) -> jnp.ndarray:
     steps = [(e.key, (lambda e=e: e.call(A, x, policy=policy)))
              for e in _spmv_chain(A, policy)]
-    return _run_chain(steps, policy, "SpMV")
+    return _run_chain(steps, policy, "SpMV", "spmv")
 
 
 def _dispatch_spmm(A, X, policy: ExecutionPolicy) -> jnp.ndarray:
@@ -309,7 +313,8 @@ def _dispatch_spmm(A, X, policy: ExecutionPolicy) -> jnp.ndarray:
         try:
             if plan is not None:
                 plan.fire("kernel", entry.key)
-            Y = entry.call(A, X, policy=policy)
+            with _obs.scope("spmm", entry.key.format, entry.key.backend):
+                Y = entry.call(A, X, policy=policy)
             if plan is not None:
                 Y = plan.corrupt("nonfinite", entry.key, Y)
         except Exception as e:
@@ -375,7 +380,7 @@ def _dispatch_masked_spmv(A, x, row_mask, policy: ExecutionPolicy) -> jnp.ndarra
             f"no masked SpMV for format {A.format!r} under chain {policy.backends}; "
             f"tried [{'; '.join(tried)}]")
     steps = _health.registry().order(steps, key_of=lambda s: s[0])
-    return _run_chain(steps, policy, "masked SpMV")
+    return _run_chain(steps, policy, "masked SpMV", "masked_spmv")
 
 
 def masked_spmv(A, x: jnp.ndarray, row_mask: jnp.ndarray,

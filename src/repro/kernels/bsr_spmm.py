@@ -81,6 +81,7 @@ def bsr_spmm(bcols: jnp.ndarray, blocks: jnp.ndarray, X: jnp.ndarray,
             out_specs=pl.BlockSpec((bs, nf_tile), lambda b, f, w, bc: (b, f)),
         ),
         out_shape=jax.ShapeDtypeStruct((nbrows * bs, nf_pad), jnp.float32),
+        name="bsr_spmm",
         interpret=interpret_mode(interpret),
     )(flat_bcols, blocks, Xp)
     return y[:, :nf]

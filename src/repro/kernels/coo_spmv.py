@@ -90,6 +90,7 @@ def coo_spmv(row: jnp.ndarray, col: jnp.ndarray, val: jnp.ndarray, x: jnp.ndarra
         in_specs=[lane_spec, lane_spec, lane_spec],
         out_specs=pl.BlockSpec((rw, 1), lambda t: (0, 0)),   # resident, accumulated
         out_shape=jax.ShapeDtypeStruct((rw, 1), jnp.float32),
+        name="coo_spmv",
         interpret=interpret_mode(interpret),
     )(_lanes(row.astype(jnp.int32), nnz_pad, tile, nrows),
       _lanes(xg, nnz_pad, tile), _lanes(val.astype(jnp.float32), nnz_pad, tile))
@@ -174,6 +175,7 @@ def scoo_spmv_tiled(row, col, val, slice_ids, ctile, x, nrows: int,
             out_specs=pl.BlockSpec((rw, 1), lambda t, sid: (sid[t], 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nrows_pad, 1), jnp.float32),
+        name="scoo_spmv_tiled",
         interpret=interpret_mode(interpret),
     )(slice_ids, row.reshape(nblocks, 1, tile), xg.reshape(nblocks, 1, tile),
       val.astype(jnp.float32).reshape(nblocks, 1, tile))

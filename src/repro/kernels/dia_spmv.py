@@ -134,6 +134,7 @@ def dia_spmv(offsets: jnp.ndarray, data: jnp.ndarray, x: jnp.ndarray,
             out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i, offs: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nrows_pad // LANES, LANES), jnp.float32),
+        name="dia_spmv",
         interpret=interpret_mode(interpret),
     )(offsets, _chunked(x, n, pre), _chunked(data, nrows_pad).reshape(
         ndiags, nrows_pad // LANES, LANES))
@@ -208,6 +209,7 @@ def dia_spmv_tiled(offs_t: jnp.ndarray, dat_w: jnp.ndarray, x: jnp.ndarray,
             out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i, t, offs: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nrows_pad // LANES, LANES), jnp.float32),
+        name="dia_spmv_tiled",
         interpret=interpret_mode(interpret),
     )(offs_t, x_c, dat_c)
     return y.reshape(-1)[:nrows].astype(dat_w.dtype)

@@ -72,6 +72,7 @@ def ell_spmv(indices: jnp.ndarray, data: jnp.ndarray, x: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, br), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, nrows_pad), jnp.float32),
+        name="ell_spmv",
         interpret=interpret_mode(interpret),
     )(xg, dat)
     return y[0, :nrows].astype(data.dtype)
@@ -118,6 +119,7 @@ def ell_spmv_tiled(idx_t: jnp.ndarray, dat_t: jnp.ndarray, prb: jnp.ndarray,
             out_specs=pl.BlockSpec((None, 1, br), lambda p, rb: (rb[p], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nrb, 1, br), jnp.float32),
+        name="ell_spmv_tiled",
         interpret=interpret_mode(interpret),
     )(prb, xg, dat_t.astype(jnp.float32))
     return y.reshape(-1)[:nrows].astype(dat_t.dtype)

@@ -105,6 +105,7 @@ def scs_spmv(btile, bwin, lsl, idx2, dat2, perm, x, *, nrows: int,
             out_specs=pl.BlockSpec((None, sw, C), lambda b, bw: (bw[b], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nwin, sw, C), jnp.float32),
+        name="sell_spmv",
         interpret=interpret_mode(interpret),
     )(bwin, lsl.reshape(-1, 1), xg, dat2.astype(jnp.float32))
 

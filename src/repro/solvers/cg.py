@@ -22,6 +22,11 @@ XLA's SPMD partitioner lowers each dot product to a per-shard partial
 reduction followed by an ``all-reduce`` — HPCG's ``MPI_Allreduce`` — and
 the AXPYs stay purely local. The *same* solver source therefore runs
 single- and multi-device, which is the point of the abstraction.
+
+**Device scopes.** Each solver traces under the scope ``cg``, with the
+matvec under ``cg/spmv``, every application of the preconditioner under
+``cg/precond`` and the dots, AXPYs and stop test under ``cg/vector``
+(``repro.core.obs``), so a profile of the solve splits by these.
 """
 from __future__ import annotations
 
@@ -29,6 +34,8 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.obs import scope
 
 
 def as_matvec(A) -> Callable:
@@ -107,17 +114,20 @@ def cg_solve(spmv_fn: Callable, b: jnp.ndarray, iters: int):
 
     def body(_, state):
         x, r, p, rs = state
-        Ap = spmv_fn(p)
-        alpha = rs / jnp.maximum(pdot(p, Ap), 1e-30)
-        x = axpy(alpha, p, x)
-        r = axpy(-alpha, Ap, r)
-        rs_new = pdot(r, r)
-        p = axpy(rs_new / jnp.maximum(rs, 1e-30), p, r)
+        with scope("spmv"):
+            Ap = spmv_fn(p)
+        with scope("vector"):
+            alpha = rs / jnp.maximum(pdot(p, Ap), 1e-30)
+            x = axpy(alpha, p, x)
+            r = axpy(-alpha, Ap, r)
+            rs_new = pdot(r, r)
+            p = axpy(rs_new / jnp.maximum(rs, 1e-30), p, r)
         return x, r, p, rs_new
 
-    x0 = jnp.zeros_like(b)
-    state = (x0, b, b, pdot(b, b))
-    x, r, p, rs = jax.lax.fori_loop(0, iters, body, state)
+    with scope("cg"):
+        with scope("vector"):
+            state = (jnp.zeros_like(b), b, b, pdot(b, b))
+        x, r, p, rs = jax.lax.fori_loop(0, iters, body, state)
     return x, rs
 
 
@@ -140,20 +150,27 @@ def pcg_solve(spmv_fn: Callable, b: jnp.ndarray, iters: int,
 
     def body(_, state):
         x, r, p, rz = state
-        Ap = spmv_fn(p)
-        alpha = rz / jnp.maximum(pdot(p, Ap), 1e-30)
-        x = axpy(alpha, p, x)
-        r = axpy(-alpha, Ap, r)
-        z = M(r)
-        rz_new = pdot(r, z)
-        p = axpy(rz_new / jnp.maximum(rz, 1e-30), p, z)
+        with scope("spmv"):
+            Ap = spmv_fn(p)
+        with scope("vector"):
+            alpha = rz / jnp.maximum(pdot(p, Ap), 1e-30)
+            x = axpy(alpha, p, x)
+            r = axpy(-alpha, Ap, r)
+        with scope("precond"):
+            z = M(r)
+        with scope("vector"):
+            rz_new = pdot(r, z)
+            p = axpy(rz_new / jnp.maximum(rz, 1e-30), p, z)
         return x, r, p, rz_new
 
-    x0 = jnp.zeros_like(b)
-    z0 = M(b)
-    state = (x0, b, z0, pdot(b, z0))
-    x, r, p, rz = jax.lax.fori_loop(0, iters, body, state)
-    return x, pdot(r, r)
+    with scope("cg"):
+        with scope("precond"):
+            z0 = M(b)
+        with scope("vector"):
+            state = (jnp.zeros_like(b), b, z0, pdot(b, z0))
+        x, r, p, rz = jax.lax.fori_loop(0, iters, body, state)
+        with scope("vector"):
+            return x, pdot(r, r)
 
 
 class CGInfo(NamedTuple):
@@ -193,31 +210,40 @@ def cg(A, b: jnp.ndarray, *, tol: float = 1e-6, maxiter: int = 500,
     """
     spmv_fn = as_matvec(A)
     M = precond if precond is not None else (lambda r: r)
-    bnorm = jnp.maximum(pnorm(b), 1e-30)
 
     def cond(state):
         _, r, _, _, k = state
-        rn = pnorm(r)
-        # non-finite residual must exit the loop, not spin to maxiter: the
-        # NaN case already does (NaN > t is False) but +Inf would not
-        return jnp.isfinite(rn) & (rn > tol * bnorm) & (k < maxiter)
+        with scope("vector"):
+            rn = pnorm(r)
+            # non-finite residual must exit the loop, not spin to maxiter: the
+            # NaN case already does (NaN > t is False) but +Inf would not
+            return jnp.isfinite(rn) & (rn > tol * bnorm) & (k < maxiter)
 
     def body(state):
         x, r, p, rz, k = state
-        Ap = spmv_fn(p)
-        alpha = rz / jnp.maximum(pdot(p, Ap), 1e-30)
-        x = axpy(alpha, p, x)
-        r = axpy(-alpha, Ap, r)
-        z = M(r)
-        rz_new = pdot(r, z)
-        p = axpy(rz_new / jnp.maximum(rz, 1e-30), p, z)
-        return x, r, p, rz_new, k + 1
+        with scope("spmv"):
+            Ap = spmv_fn(p)
+        with scope("vector"):
+            alpha = rz / jnp.maximum(pdot(p, Ap), 1e-30)
+            x = axpy(alpha, p, x)
+            r = axpy(-alpha, Ap, r)
+        with scope("precond"):
+            z = M(r)
+        with scope("vector"):
+            rz_new = pdot(r, z)
+            p = axpy(rz_new / jnp.maximum(rz, 1e-30), p, z)
+            return x, r, p, rz_new, k + 1
 
-    x0 = jnp.zeros_like(b)
-    z0 = M(b)
-    state = (x0, b, z0, pdot(b, z0), jnp.int32(0))
-    x, r, _, _, k = jax.lax.while_loop(cond, body, state)
-    return CGInfo(x, k, pnorm(r) / bnorm)
+    with scope("cg"):
+        with scope("vector"):
+            bnorm = jnp.maximum(pnorm(b), 1e-30)
+        with scope("precond"):
+            z0 = M(b)
+        with scope("vector"):
+            state = (jnp.zeros_like(b), b, z0, pdot(b, z0), jnp.int32(0))
+        x, r, _, _, k = jax.lax.while_loop(cond, body, state)
+        with scope("vector"):
+            return CGInfo(x, k, pnorm(r) / bnorm)
 
 
 class CGDiagnostics(NamedTuple):

@@ -11,6 +11,12 @@ per-level, Table III style — each level's sparsity pattern may pick a
 different winning format/backend), and the restriction/prolongation maps
 (COO containers with one unit entry per coarse point). The V-cycle is
 therefore jittable end-to-end and retargets with the dispatch table.
+
+Level ``i`` of a cycle traces under the device scope ``mg/L<i>``, its
+steps under ``presmooth``, ``residual``, ``restrict``, ``prolong``,
+``postsmooth`` and, at the coarsest level, ``coarse``; the host build
+records the spans ``mg.build``, ``mg.level`` and ``mg.transfer``
+(``repro.core.obs``).
 """
 from __future__ import annotations
 
@@ -25,6 +31,7 @@ import scipy.sparse as sp
 
 from repro.core import SparseOperator, as_operator
 from repro.core import matrices as M
+from repro.core import obs
 from repro.core.autotune import autotune_spmv
 
 from .symgs import SymGS
@@ -38,12 +45,14 @@ def injection_operators(nx: int, ny: int, nz: int,
     correction scatters back onto the injected points and the V-cycle stays a
     symmetric preconditioner.
     """
-    f2c = M.coarsen_injection(nx, ny, nz)
-    nf, nc = nx * ny * nz, len(f2c)
-    ones = np.ones(nc, np.float64)
-    R = sp.csr_matrix((ones, (np.arange(nc), f2c)), shape=(nc, nf))
-    P = sp.csr_matrix((ones, (f2c, np.arange(nc))), shape=(nf, nc))
-    return as_operator(R, "coo", dtype=dtype), as_operator(P, "coo", dtype=dtype)
+    with obs.span("mg.transfer", grid=(nx, ny, nz)):
+        f2c = M.coarsen_injection(nx, ny, nz)
+        nf, nc = nx * ny * nz, len(f2c)
+        ones = np.ones(nc, np.float64)
+        R = sp.csr_matrix((ones, (np.arange(nc), f2c)), shape=(nc, nf))
+        P = sp.csr_matrix((ones, (f2c, np.arange(nc))), shape=(nf, nc))
+        return (as_operator(R, "coo", dtype=dtype),
+                as_operator(P, "coo", dtype=dtype))
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -103,32 +112,42 @@ class VCycle:
         if mode not in ("run", "predict"):
             raise ValueError(f"retuned mode {mode!r}: expected 'run' or 'predict'")
         levels = []
-        for li, l in enumerate(self.levels):
-            if li == 0 and finest is not None:
-                op = finest
-            elif mode == "predict":
-                op = l.A.tune(candidates=candidates, mode="predict")
-            else:
-                op = autotune_spmv(l.A, candidates=candidates).operator
-            levels.append(MGLevel(l.grid, op, l.smoother.with_operator(op),
-                                  l.R, l.P))
+        with obs.span("tune.retarget", mode=mode):
+            for li, l in enumerate(self.levels):
+                if li == 0 and finest is not None:
+                    op = finest
+                elif mode == "predict":
+                    with obs.span("tune.predict", level=li):
+                        op = l.A.tune(candidates=candidates, mode="predict")
+                else:
+                    op = autotune_spmv(l.A, candidates=candidates).operator
+                levels.append(MGLevel(l.grid, op, l.smoother.with_operator(op),
+                                      l.R, l.P))
         return VCycle(tuple(levels), self.pre, self.post, self.coarse_sweeps)
 
     def _apply(self, li: int, r: jnp.ndarray) -> jnp.ndarray:
         lvl = self.levels[li]
-        x = jnp.zeros_like(r)
-        if li == len(self.levels) - 1:  # coarsest: smooth it out
-            for _ in range(self.coarse_sweeps):
-                x = lvl.smoother.sweep(r, x)
+        with obs.scope("mg", f"L{li}"):
+            x = jnp.zeros_like(r)
+            if li == len(self.levels) - 1:  # coarsest: smooth it out
+                with obs.scope("coarse"):
+                    for _ in range(self.coarse_sweeps):
+                        x = lvl.smoother.sweep(r, x)
+                return x
+            with obs.scope("presmooth"):
+                for _ in range(self.pre):
+                    x = lvl.smoother.sweep(r, x)
+            with obs.scope("residual"):
+                res = r - lvl.A @ x
+            with obs.scope("restrict"):
+                rc = lvl.R @ res
+            xc = self._apply(li + 1, rc)
+            with obs.scope("prolong"):
+                x = x + lvl.P @ xc
+            with obs.scope("postsmooth"):
+                for _ in range(self.post):
+                    x = lvl.smoother.sweep(r, x)
             return x
-        for _ in range(self.pre):
-            x = lvl.smoother.sweep(r, x)
-        res = r - lvl.A @ x
-        xc = self._apply(li + 1, lvl.R @ res)
-        x = x + lvl.P @ xc
-        for _ in range(self.post):
-            x = lvl.smoother.sweep(r, x)
-        return x
 
     def __call__(self, r: jnp.ndarray) -> jnp.ndarray:
         return self._apply(0, r)
@@ -248,17 +267,21 @@ def build_mg(nx: int, ny: int, nz: int, *, depth: int = 4, pre: int = 1,
     """
     levels = []
     grid = (nx, ny, nz)
-    for li in range(depth):
-        A_sp = M.fdm27(*grid)
-        op = as_operator(A_sp, fmt).using("plain")
-        smoother = SymGS.build(A_sp, operator=op, method=method, dtype=dtype)
-        last = li == depth - 1 or not coarsenable(grid)
-        R = P = None
-        if not last:
-            R, P = injection_operators(*grid, dtype=dtype)
-        levels.append(MGLevel(grid, op, smoother, R, P))
-        if last:
-            break
-        grid = tuple(d // 2 for d in grid)
-    vc = VCycle(tuple(levels), pre=pre, post=post, coarse_sweeps=coarse_sweeps)
-    return vc.retuned(candidates) if tune else vc
+    with obs.span("mg.build", grid=grid, depth=depth, fmt=fmt):
+        for li in range(depth):
+            with obs.span("mg.level", level=li, grid=grid):
+                A_sp = M.fdm27(*grid)
+                op = as_operator(A_sp, fmt).using("plain")
+                smoother = SymGS.build(A_sp, operator=op, method=method,
+                                       dtype=dtype)
+                last = li == depth - 1 or not coarsenable(grid)
+                R = P = None
+                if not last:
+                    R, P = injection_operators(*grid, dtype=dtype)
+                levels.append(MGLevel(grid, op, smoother, R, P))
+            if last:
+                break
+            grid = tuple(d // 2 for d in grid)
+        vc = VCycle(tuple(levels), pre=pre, post=post,
+                    coarse_sweeps=coarse_sweeps)
+        return vc.retuned(candidates) if tune else vc

@@ -14,6 +14,10 @@ Two interchangeable schedules:
 Because the color sweeps are ordinary dispatch-table SpMVs, SymGS retargets
 across formats and backends exactly like any other kernel — the point of the
 Morpheus abstraction, now covering HPCG's dominant non-SpMV phase.
+
+A sweep traces under the device scopes ``symgs/fwd`` and ``symgs/bwd``; the
+host build records the spans ``symgs.build``, ``symgs.colour`` and
+``symgs.schedule`` (``repro.core.obs``).
 """
 from __future__ import annotations
 
@@ -26,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 
-from repro.core import SparseOperator, as_operator
+from repro.core import SparseOperator, as_operator, obs
 from repro.core.convert import _as_scipy
 
 
@@ -37,19 +41,20 @@ def greedy_coloring(s: sp.spmatrix) -> np.ndarray:
     update of a whole color is order-independent. The 27-point stencil
     colors in 8 (the 2x2x2 parity classes); greedy natural order finds it.
     """
-    s = s.tocsr()
-    pattern = ((s != 0) + (s != 0).T).tocsr()  # symmetrise: GS couples both ways
-    n = s.shape[0]
-    colors = np.full(n, -1, np.int32)
-    indptr, indices = pattern.indptr, pattern.indices
-    for i in range(n):
-        neigh = indices[indptr[i]:indptr[i + 1]]
-        used = {colors[j] for j in neigh if j != i and colors[j] >= 0}
-        c = 0
-        while c in used:
-            c += 1
-        colors[i] = c
-    return colors
+    with obs.span("symgs.colour", nrows=s.shape[0]):
+        s = s.tocsr()
+        pattern = ((s != 0) + (s != 0).T).tocsr()  # symmetrise: GS couples both ways
+        n = s.shape[0]
+        colors = np.full(n, -1, np.int32)
+        indptr, indices = pattern.indptr, pattern.indices
+        for i in range(n):
+            neigh = indices[indptr[i]:indptr[i + 1]]
+            used = {colors[j] for j in neigh if j != i and colors[j] >= 0}
+            c = 0
+            while c in used:
+                c += 1
+            colors[i] = c
+        return colors
 
 
 def _padded_offdiag(s: sp.csr_matrix) -> Tuple[np.ndarray, np.ndarray]:
@@ -96,25 +101,28 @@ class SymGS:
         """``a`` is anything ``as_operator`` accepts; ``operator`` optionally
         overrides the SpMV operator (e.g. a tuned one) while the schedule is
         still derived from ``a``'s host-side structure."""
-        s = _as_scipy(a).tocsr()
-        n = s.shape[0]
-        d = np.asarray(s.diagonal(), np.float64)
-        if not np.all(d != 0):
-            raise ValueError("SymGS needs a nonzero diagonal on every row")
-        op = operator if operator is not None else as_operator(s, "csr")
-        diag = jnp.asarray(d, dtype)
-        if method == "multicolor":
-            colors = greedy_coloring(s)
-            ncolors = int(colors.max()) + 1 if n else 1
-            masks = jnp.asarray(
-                np.stack([colors == c for c in range(ncolors)]) if n
-                else np.ones((1, 0), bool))
-            return cls(op, diag, masks=masks, method=method)
-        if method == "reference":
-            idx, val = _padded_offdiag(s)
-            return cls(op, diag, off_idx=jnp.asarray(idx),
-                       off_val=jnp.asarray(val, dtype), method=method)
-        raise ValueError(f"unknown SymGS method {method!r}")
+        with obs.span("symgs.build", method=method):
+            s = _as_scipy(a).tocsr()
+            n = s.shape[0]
+            d = np.asarray(s.diagonal(), np.float64)
+            if not np.all(d != 0):
+                raise ValueError("SymGS needs a nonzero diagonal on every row")
+            op = operator if operator is not None else as_operator(s, "csr")
+            diag = jnp.asarray(d, dtype)
+            if method == "multicolor":
+                colors = greedy_coloring(s)
+                with obs.span("symgs.schedule"):
+                    ncolors = int(colors.max()) + 1 if n else 1
+                    masks = jnp.asarray(
+                        np.stack([colors == c for c in range(ncolors)]) if n
+                        else np.ones((1, 0), bool))
+                return cls(op, diag, masks=masks, method=method)
+            if method == "reference":
+                with obs.span("symgs.schedule"):
+                    idx, val = _padded_offdiag(s)
+                return cls(op, diag, off_idx=jnp.asarray(idx),
+                           off_val=jnp.asarray(val, dtype), method=method)
+            raise ValueError(f"unknown SymGS method {method!r}")
 
     @property
     def ncolors(self) -> int:
@@ -186,11 +194,16 @@ class SymGS:
         """One symmetric sweep (forward then backward) from iterate ``x``."""
         if x is None:
             x = jnp.zeros_like(r)
-        if self.method == "multicolor":
-            x = self._color_half(r, x, self.masks)
-            return self._color_half(r, x, self.masks[::-1])
-        x = self._tri_half(r, x, reverse=False)
-        return self._tri_half(r, x, reverse=True)
+        with obs.scope("symgs"):
+            if self.method == "multicolor":
+                with obs.scope("fwd"):
+                    x = self._color_half(r, x, self.masks)
+                with obs.scope("bwd"):
+                    return self._color_half(r, x, self.masks[::-1])
+            with obs.scope("fwd"):
+                x = self._tri_half(r, x, reverse=False)
+            with obs.scope("bwd"):
+                return self._tri_half(r, x, reverse=True)
 
     def __call__(self, r) -> jnp.ndarray:
         """Apply the SymGS preconditioner: M^-1 r (sweep from zero)."""

@@ -25,6 +25,7 @@ DOCTEST_MODULES = [
     "repro.distributed_op.operator",
     "repro.distributed_op.tune",
     "repro.core.health",
+    "repro.core.obs",
 ]
 
 REQUIRED_DOCS = ["architecture.md", "formats.md", "hpcg.md", "serving.md",

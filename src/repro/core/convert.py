@@ -230,13 +230,23 @@ def to_dia(a, dtype=jnp.float32, col_tile: ColTile = None):
     data = np.zeros((len(offs), nrows), np.float64)
     # unbuffered add: duplicate entries accumulate, in entry order
     np.add.at(data, (np.searchsorted(offs, entry_offs), s.row), s.data)
-    ct = _resolve_col_tile(ncols, col_tile)
-    plan = None
+    extent = int(np.abs(offs).max())
+    # DIA's own residency rule (x plus the band's reach): under the default
+    # budget a resident band runs the resident kernel, which reads the
+    # lane-dense values made here once; a column-tile plan is built only
+    # where x does not fit or a policy asked for one by its tile width
+    resident = tiling.dia_resident(ncols, extent, tiling.resident_cols())
+    ct = None if col_tile is None and resident else _resolve_col_tile(ncols, col_tile)
+    vals = data.astype(np.dtype(dtype))
+    plan = lanes = None
     if ct is not None:
-        plan = tiling.build_dia_col_plan(
-            offs, data.astype(np.dtype(dtype)), (nrows, ncols), ct).jaxify()
-    return DIA(jnp.asarray(offs, jnp.int32), jnp.asarray(data, dtype),
-               (nrows, ncols), plan, extent=int(np.abs(offs).max()))
+        plan = tiling.build_dia_col_plan(offs, vals, (nrows, ncols), ct).jaxify()
+    if ct is None or resident:
+        from repro.kernels.dia_spmv import dia_lanes
+
+        lanes = jnp.asarray(dia_lanes(vals))
+    return DIA(jnp.asarray(offs, jnp.int32), jnp.asarray(vals),
+               (nrows, ncols), plan, lanes, extent=extent)
 
 
 def _row_entry_positions(take: np.ndarray):

@@ -181,12 +181,20 @@ def build_stacked(mats: Sequence[sp.spmatrix], fmt: str, dtype=jnp.float32):
         nnz = max(1, max(int(m.nnz) for m in mats))
         cs = [_pad_csr(to_csr(m, dtype=dtype, plan=False), nnz) for m in mats]
     elif fmt == "dia":
+        from repro.kernels.dia_spmv import dia_lanes
+
         cs = [to_dia(m, dtype=dtype, col_tile=False) for m in mats]
         nd = max(c.ndiags for c in cs)
         # extent is static aux data: parts must share one value to stack, and
         # the max across parts is a valid (if loose) bound for each
         ext = max((c.extent or 0) for c in cs)
-        cs = [dataclasses.replace(_pad_dia(c, nd), extent=ext) for c in cs]
+        # the resident kernel's value layout stacks too, from the padded
+        # values, when every part's band lets x stay resident
+        lanes = all(c.lanes is not None for c in cs)
+        cs = [_pad_dia(c, nd) for c in cs]
+        cs = [dataclasses.replace(
+            c, extent=ext, lanes=dia_lanes(c.data) if lanes else None)
+            for c in cs]
     elif fmt == "ell":
         w = max(1, max(int(np.diff(m.indptr).max() if m.nnz else 1) for m in mats))
         cs = [to_ell(m, dtype=dtype, width=w, col_tile=False) for m in mats]

@@ -202,6 +202,12 @@ class DIA:
     data: jnp.ndarray     # (ndiags, nrows) float, 0 where out of range
     shape: Shape = _aux()
     plan: Any = None  # optional KernelPlan ("dia-cols" per-tile diagonals)
+    #: ``data`` in the resident Pallas kernel's lane-dense layout
+    #: (``kernels.dia_spmv.dia_lanes``: rows on the 128 lanes, zero-padded
+    #: to whole sub-tiles), built once by ``to_dia`` so no call copies A; a
+    #: device reshape of ``data`` is no view (its (8, 128) tiles hold 8
+    #: diagonals, the kernel's hold 1024 rows of one). None: laid out per call
+    lanes: Any = None
     #: static upper bound on max|offset| (set by ``to_dia``) — lets the
     #: Pallas fit predicate and x padding stay tight *under jit tracing*,
     #: where the offsets array itself is abstract; None = unknown (the

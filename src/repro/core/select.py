@@ -297,7 +297,7 @@ def pallas_strategy_for(f: MatrixFeatures, policy: ExecutionPolicy,
     needs the built container)."""
     if fmt == "dia":
         # the extent-tightened resident test (docs/formats.md)
-        if f.ncols + 2 * f.band_extent <= 4 * policy.resident_cols():
+        if tiling.dia_resident(f.ncols, f.band_extent, policy.resident_cols()):
             return "resident"
         return "tiled"
     if fmt == "coo":

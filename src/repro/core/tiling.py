@@ -90,6 +90,12 @@ def resident_cols(max_resident_cols: int = DEFAULT_MAX_RESIDENT_COLS,
     return min(max_resident_cols, vmem_budget_bytes // 16)
 
 
+def dia_resident(ncols: int, extent: int, resident_cols: int) -> bool:
+    """DIA's resident-x fit: x with ``extent`` columns of padding on each
+    side (the band's reach) within four times ``resident_cols``."""
+    return ncols + 2 * extent <= 4 * resident_cols
+
+
 def select_col_tile(ncols: int,
                     max_resident_cols: int = DEFAULT_MAX_RESIDENT_COLS,
                     vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,

@@ -27,12 +27,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tiling
 from repro.core.formats import BSR, COO, CSR, DIA, ELL, SELL
 from repro.core.spmv import register_masked_spmv, register_spmm, register_spmv
 
 from .bsr_spmm import bsr_spmm
 from .coo_spmv import coo_spmv, scoo_spmv_tiled
-from .dia_spmv import dia_spmv, dia_spmv_tiled
+from .dia_spmv import dia_spmv, dia_spmv_lanes, dia_spmv_tiled
 from .ell_spmv import ell_spmv, ell_spmv_tiled
 from .sell_spmv import scs_spmv_from_plan
 
@@ -82,7 +83,7 @@ def _dia_resident(A: DIA, policy) -> bool:
     # the worst-case row count when traced
     ext = _dia_extent(A)
     pad = A.shape[0] if ext is None else ext
-    return A.shape[1] + 2 * pad <= 4 * policy.resident_cols()
+    return tiling.dia_resident(A.shape[1], pad, policy.resident_cols())
 
 
 def _dia_ok(A: DIA, policy) -> bool:
@@ -159,7 +160,11 @@ def pallas_strategy(A, policy) -> str | None:
 @register_spmv("dia", "pallas", supports=_dia_ok, needs_policy=True)
 def dia_spmv_pallas(A: DIA, x, policy):
     if pallas_strategy(A, policy) == "resident":
-        return dia_spmv(A.offsets, A.data, x, extent=_dia_extent(A))
+        kw = dict(extent=_dia_extent(A),
+                  vmem_budget_bytes=policy.vmem_budget_bytes)
+        if A.lanes is not None:
+            return dia_spmv_lanes(A.offsets, A.lanes, x, nrows=A.shape[0], **kw)
+        return dia_spmv(A.offsets, A.data, x, **kw)
     offs_t, dat_w = A.plan.arrays
     return dia_spmv_tiled(offs_t, dat_w, x, nrows=A.shape[0], col_tile=A.plan.ct)
 

@@ -4,12 +4,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import from_dense
+from repro.core import ExecutionPolicy, SparseOperator, from_dense, obs
 from repro.core import matrices as M
-from repro.kernels import ref
+from repro.core.tiling import DEFAULT_VMEM_BUDGET_BYTES
+from repro.kernels import ops, ref
+from repro.kernels import dia_spmv as dia_mod
 from repro.kernels.bsr_spmm import bsr_spmm
 from repro.kernels.coo_spmv import build_scoo, coo_spmv, scoo_spmv
-from repro.kernels.dia_spmv import dia_spmv
+from repro.kernels.dia_spmv import dia_spmv, dia_spmv_lanes
 from repro.kernels.ell_spmv import ell_spmv
 
 SHAPES = [(32, 32), (100, 100), (257, 129), (512, 768)]
@@ -49,6 +51,100 @@ def test_dia_kernel_sweep(shape, dtype):
     want = np.asarray(ref.dia_spmv_ref(A.offsets, A.data.astype(jnp.float32),
                                        x.astype(jnp.float32), A.shape))
     np.testing.assert_allclose(got, want, **_tol(dtype))
+
+
+def _band(n, m, offsets, seed):
+    """An n x m matrix with random values on the given diagonals."""
+    import scipy.sparse as sp
+    rng = np.random.default_rng(seed)
+    return sp.diags([rng.standard_normal(n) for _ in offsets], list(offsets),
+                    shape=(n, m), format="csr")
+
+
+#: matrices whose resident DIA kernel runs cross its row-block edges: stencils
+#: whose rows are (13³, 26³, 52³) and are not (32³, 32 chunks) a multiple of a
+#: 1024-row chunk, a band reaching past one 8192-row block of a 3-step grid,
+#: rectangles, and bf16 values
+DIA_BLOCK_CASES = {
+    "stencil13": lambda: (M.fdm27(13, 13, 13), jnp.float32),
+    "stencil26": lambda: (M.fdm27(26, 26, 26), jnp.float32),
+    "stencil32": lambda: (M.fdm27(32, 32, 32), jnp.float32),
+    "stencil52": lambda: (M.fdm27(52, 52, 52), jnp.float32),
+    "band_past_block": lambda: (_band(20000, 20000, (-9000, -1, 0, 1, 9000), 14),
+                                jnp.float32),
+    "rect_tall": lambda: (_band(5000, 3000, (-4500, -7, 0, 2999), 15), jnp.float32),
+    "rect_wide": lambda: (_band(3000, 7000, (-2999, 0, 2500, 6500), 16), jnp.float32),
+    "stencil26_bf16": lambda: (M.fdm27(26, 26, 26), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DIA_BLOCK_CASES))
+def test_dia_resident_blocks_match_reference(case):
+    """The resident DIA kernel against the Algorithm-3 oracle, run every way
+    a caller reaches it: from raw values (laid out per call), from the
+    container's lane-dense values, with ``extent=None`` under ``jit``,
+    through the row-masked wrapper SymGS runs, and vmapped as the SpMM
+    lane."""
+    s, dtype = DIA_BLOCK_CASES[case]()
+    A = from_dense(s, "dia", dtype=dtype)
+    assert A.lanes is not None
+    n, m = A.shape
+    rng = np.random.default_rng(17)
+    x = jnp.asarray(rng.standard_normal(m), dtype)
+    f32 = lambda v: np.asarray(v, np.float32)
+    want = f32(ref.dia_spmv_ref(A.offsets, A.data.astype(jnp.float32),
+                                x.astype(jnp.float32), A.shape))
+    tol = _tol(dtype)
+    np.testing.assert_allclose(f32(dia_spmv(A.offsets, A.data, x, extent=A.extent)),
+                               want, **tol)
+    np.testing.assert_allclose(
+        f32(dia_spmv_lanes(A.offsets, A.lanes, x, nrows=n, extent=A.extent)),
+        want, **tol)
+    no_extent = jax.jit(lambda o, d, x: dia_spmv(o, d, x))
+    np.testing.assert_allclose(f32(no_extent(A.offsets, A.data, x)), want, **tol)
+
+    pol = ExecutionPolicy(backends=("pallas",), allow_fallback=False)
+    assert ops.pallas_strategy(A, pol) == "resident"
+    mask = rng.random(n) < 0.4
+    np.testing.assert_allclose(
+        f32(ops.dia_masked_spmv_pallas(A, x, jnp.asarray(mask), pol)),
+        np.where(mask, want, 0), **tol)
+    X = jnp.stack([x, -2 * x, x * x], axis=1)
+    Y = f32(SparseOperator(A, pol) @ X)
+    for k in range(X.shape[1]):
+        col = f32(ref.dia_spmv_ref(A.offsets, A.data.astype(jnp.float32),
+                                   X[:, k].astype(jnp.float32), A.shape))
+        np.testing.assert_allclose(Y[:, k], col, **tol)
+    if case == "band_past_block":  # the case reaches past a whole row block
+        nch = dia_mod.lane_rows(n) // 1024
+        block = dia_mod.block_chunks(nch, A.ndiags, 4, nch, DEFAULT_VMEM_BUDGET_BYTES)
+        assert A.extent > block * 1024 and nch > block
+
+
+@pytest.mark.parametrize("grid,steps", [(13, (1, 1)), (52, (4, 12)), (104, (10, 99))])
+def test_dia_block_rule_fits_the_budget(grid, steps):
+    """The row block comes from the shapes: one step at 13³, tens at 104³,
+    and the resident x plus the double-buffered value and y blocks stay
+    inside the policy's VMEM budget."""
+    n = grid ** 3
+    ext = grid * grid + grid + 1
+    nch = dia_mod.lane_rows(n) // 1024
+    x_chunks = -(-(ext + nch * 1024 + ext) // 1024) + 1
+    block = dia_mod.block_chunks(nch, 27, 4, x_chunks, DEFAULT_VMEM_BUDGET_BYTES)
+    assert block % dia_mod.SUB == 0
+    assert steps[0] <= -(-nch // block) <= steps[1]
+    vmem = 4 * 1024 * (x_chunks + 2 * block * (27 + 1))
+    assert vmem + dia_mod.VMEM_RESERVE <= DEFAULT_VMEM_BUDGET_BYTES
+
+
+def test_dia_grid_steps_counter():
+    """A recording around the kernel's trace counts its grid steps."""
+    s = _band(20000, 20000, (-9000, 0, 9000), 18)
+    A = from_dense(s, "dia")
+    x = jnp.ones((20000,), jnp.float32)
+    with obs.recording() as rec:
+        dia_spmv_lanes(A.offsets, A.lanes, x, nrows=20000, extent=A.extent + 1)
+    assert rec.counts == {"dia_spmv.grid_steps": 3}
 
 
 @pytest.mark.parametrize("shape", SHAPES)

@@ -149,6 +149,36 @@ def test_masked_kernel_compiles_for_v5e(fmt, strategy, containers, one_chip,
     _compile(lambda A, x, m: masked(A, x, m, pol), _struct(A, one_chip), x, m)
 
 
+@pytest.mark.parametrize("grid,masked,dtype", [
+    (13, False, jnp.float32), (13, True, jnp.float32),
+    (104, False, jnp.float32), (104, True, jnp.float32),
+    (13, False, jnp.bfloat16), (13, False, jnp.float16)])
+def test_dia_resident_blocks_compile_for_v5e(grid, masked, dtype, one_chip,
+                                             monkeypatch):
+    """The resident DIA kernel at the widths of the HPCG cells' coarsest and
+    finest levels, plain and masked, and in the narrow storage dtypes, as
+    dispatch runs it on a container's lane-dense values: Mosaic checks the
+    row block's VMEM use and the value loads, and the program holds no copy
+    of A (its temporaries stay far below the values)."""
+    from repro.core.formats import DIA
+    from repro.kernels.dia_spmv import lane_rows
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = grid ** 3
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt,
+                                                           sharding=one_chip)
+    lanes = S((27, lane_rows(n) // 128, 128), dtype)
+    A = DIA(S((27,), jnp.int32), S((27, n), dtype), (n, n), None, lanes,
+            extent=grid * grid + grid + 1)
+    assert ops.pallas_strategy(A, RESIDENT) == "resident"
+    if masked:
+        fn = lambda A, x, m: ops.dia_masked_spmv_pallas(A, x, m, RESIDENT)
+    else:
+        fn = lambda A, x, m: ops.dia_spmv_pallas(A, x, RESIDENT)
+    compiled = _compile(fn, A, S((n,)), S((n,), jnp.bool_))
+    assert compiled.memory_analysis().temp_size_in_bytes < 27 * n
+
+
 @pytest.mark.parametrize("fmt", ["csr", "ell"])
 def test_hpcg_solver_program_fits_for_v5e(fmt, one_chip, monkeypatch):
     """HPCG's whole convergence solver (PCG around a 4-level V-cycle of

@@ -182,6 +182,8 @@ def to_coo(a, dtype=jnp.float32, pad_to: Optional[int] = None,
     s = _as_scipy(a).tocoo()
     order = np.lexsort((s.col, s.row))  # row-major sort (Morpheus sorts too)
     row, col, val = s.row[order], s.col[order], s.data[order]
+    rowptr = np.zeros(s.shape[0] + 1, np.int32)
+    np.cumsum(np.bincount(row, minlength=s.shape[0]), out=rowptr[1:])
     ct = _resolve_col_tile(s.shape[1], col_tile)
     plan = None
     if ct is not None:
@@ -198,7 +200,7 @@ def to_coo(a, dtype=jnp.float32, pad_to: Optional[int] = None,
         col = np.concatenate([col, np.zeros(pad, np.int32)])
         val = np.concatenate([val, np.zeros(pad, val.dtype)])
     return COO(jnp.asarray(row, jnp.int32), jnp.asarray(col, jnp.int32),
-               jnp.asarray(val, dtype), tuple(s.shape), plan)
+               jnp.asarray(val, dtype), tuple(s.shape), plan, jnp.asarray(rowptr))
 
 
 def to_csr(a, dtype=jnp.float32, col_tile: ColTile = None, plan: bool = True,

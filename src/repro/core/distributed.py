@@ -176,7 +176,8 @@ def build_stacked(mats: Sequence[sp.spmatrix], fmt: str, dtype=jnp.float32):
     if fmt == "coo":
         nnz = max(1, max(int(m.nnz) for m in mats))
         cs = [to_coo(m, dtype=dtype, pad_to=None, col_tile=False) for m in mats]
-        cs = [_pad_coo(c, nnz) for c in cs]
+        # no row pointer: the padded parts must share one structure to stack
+        cs = [_pad_coo(dataclasses.replace(c, rowptr=None), nnz) for c in cs]
     elif fmt == "csr":
         nnz = max(1, max(int(m.nnz) for m in mats))
         cs = [_pad_csr(to_csr(m, dtype=dtype, plan=False), nnz) for m in mats]

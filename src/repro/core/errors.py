@@ -145,3 +145,9 @@ def validate_container(A) -> None:
             _bad("row", f"out of range [0, {nrows}]")
         if col.size and (col.min() < 0 or col.max() >= ncols):
             _bad("col", f"out of range [0, {ncols})")
+        # the in-place row sums read each row's run through the row pointer
+        if A.rowptr is not None:
+            counts = np.bincount(row[row < nrows], minlength=nrows)
+            if (np.any(np.diff(row) < 0) or not np.array_equal(
+                    np.asarray(A.rowptr), np.concatenate([[0], np.cumsum(counts)]))):
+                _bad("rowptr", "does not point into sorted rows")

@@ -131,6 +131,13 @@ class COO:
     paper's SVE COO kernel exploits exactly this to tree-reduce same-row
     products). ``row``/``col``/``val`` may be padded at the tail with
     (row=nrows, col=0, val=0) sentinels so shapes can be bucketed under jit.
+
+    ``rowptr`` is the CSR row pointer of the sorted entries, built once by
+    ``to_coo``: row ``r`` owns entries ``rowptr[r]:rowptr[r + 1]``, and pad
+    sentinels lie past ``rowptr[-1]``. With it, and rows long enough, the
+    plain SpMV sums each row's products in place instead of scatter-adding
+    them (``core.spmv.coo_spmv_plain``). Containers built by hand (or under
+    a trace) leave it ``None``; their rows need not even be sorted.
     """
 
     row: jnp.ndarray  # (nnz,) int32, sorted non-decreasing
@@ -138,6 +145,7 @@ class COO:
     val: jnp.ndarray  # (nnz,) float
     shape: Shape = _aux()
     plan: Any = None  # optional KernelPlan ("coo-cols" column-tiled stream)
+    rowptr: Any = None  # optional (nrows + 1,) int32 row pointer of `row`
 
     format: ClassVar[str] = "coo"
 

@@ -465,11 +465,75 @@ def spmm(A, X: jnp.ndarray, impl: Optional[str] = None, *,
 _FULL = jax.lax.Precision.HIGHEST
 
 
+#: Mean stored entries per row from which ``coo/plain`` sums rows in place.
+#: The in-place sum reads one value per row besides its passes over the
+#: entries, the scatter-add makes one update per entry: on a TPU v5e at 2^20
+#: rows the two cost about the same at one entry a row, and at two the
+#: in-place sum takes less than half the scatter's time.
+SEGMENTED_MIN_ROW_NNZ = 2
+
+#: Lanes of one block of the segmented scan (one vreg row on a TPU).
+_SCAN_BLOCK = 128
+
+
+def _shift(a, d: int, fill):
+    """``a`` moved ``d`` places along its last axis, ``fill`` shifted in."""
+    pad = [(0, 0)] * (a.ndim - 1) + [(d, 0)]
+    return jnp.pad(a[..., : a.shape[-1] - d], pad, constant_values=fill)
+
+
+def _segmented_scan(key, val):
+    """Inclusive sum of ``val`` over runs of equal ``key`` (sorted keys).
+
+    Entry ``i`` ends up holding the sum of the entries ``j <= i`` of its own
+    run; values of different runs are never added. Within blocks of
+    ``_SCAN_BLOCK`` lanes, ``log2`` shifted adds, each masked where the key
+    differs (sorted keys make an equal key at distance ``d`` mean the whole
+    stretch between is that run). Each block's last partial sum is then
+    scanned the same way, one level down, and carried into the block's
+    first run where that run began in an earlier block.
+    """
+    n = val.shape[0]
+    width = min(n, _SCAN_BLOCK)
+    nb = -(-n // width)
+    keys = jnp.pad(key, (0, nb * width - n), mode="edge").reshape(nb, width)
+    vals = jnp.pad(val, (0, nb * width - n)).reshape(nb, width)
+    d = 1
+    while d < width:
+        vals = vals + jnp.where(keys == _shift(keys, d, -1), _shift(vals, d, 0), 0)
+        d *= 2
+    if nb > 1:
+        tails = _segmented_scan(keys[:, -1], vals[:, -1])
+        carry = jnp.where(keys[1:, 0] == keys[:-1, -1], tails[:-1], 0)
+        carry = jnp.concatenate([jnp.zeros((1,), carry.dtype), carry])
+        vals = vals + jnp.where(keys == keys[:, :1], carry[:, None], 0)
+    return vals.reshape(-1)[:n]
+
+
+def _sorted_row_sums(row, prod, rowptr):
+    """``y[r]`` = sum of ``prod`` over row ``r``'s entries, for row-sorted
+    entries with row pointer ``rowptr``: the segmented scan's value at each
+    row's last entry, 0 for an empty row. Pad sentinels past ``rowptr[-1]``
+    are never read."""
+    end = rowptr[1:]
+    sums = _segmented_scan(row, prod)
+    return jnp.where(end > rowptr[:-1], sums[jnp.maximum(end - 1, 0)], 0)
+
+
 @register_spmv("coo", "plain")
 def coo_spmv_plain(A: COO, x):
-    """Algorithm 1: y[ai[i]] += av[i] * x[aj[i]] (segment scatter-add)."""
+    """Algorithm 1: y[ai[i]] += av[i] * x[aj[i]].
+
+    With a row pointer and at least ``SEGMENTED_MIN_ROW_NNZ`` entries a row
+    on average, each row's products are summed in place over the sorted
+    entries (:func:`_sorted_row_sums`, counted as ``coo_spmv.segmented_rows``
+    per trace); otherwise they are scatter-added into y.
+    """
     nrows = A.shape[0]
     prod = A.val * x[A.col]
+    if A.rowptr is not None and prod.shape[0] >= SEGMENTED_MIN_ROW_NNZ * nrows:
+        _obs.count("coo_spmv.segmented_rows", nrows)
+        return _sorted_row_sums(A.row, prod, A.rowptr)
     y = jnp.zeros((nrows + 1,), prod.dtype)  # +1 bucket absorbs pad sentinels
     return y.at[A.row].add(prod)[:nrows]
 

@@ -186,6 +186,104 @@ def test_scoo_kernel(slice_rows):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+def _coo_row_sum_case(case):
+    """(scipy matrix, ``to_coo`` options) of one ``coo/plain`` row-sum case."""
+    import scipy.sparse as sp
+    rng = np.random.default_rng(11)
+    if case in ("powerlaw-empty-rows", "first-last-empty"):
+        n = 3000
+        keep = rng.random(n) > 0.3 if case == "powerlaw-empty-rows" else np.ones(n)
+        keep[[0, -1]] = case == "powerlaw-empty-rows"
+        s = sp.diags(keep.astype(np.float64)) @ M.powerlaw(n, avg_nnz=12, seed=3)
+        s.eliminate_zeros()
+        return s.tocsr(), {}
+    if case == "one-row":  # 1000 entries in row 7: one run across 8 blocks
+        return sp.csr_matrix((rng.standard_normal(1000),
+                              (np.full(1000, 7), np.arange(1000))),
+                             shape=(40, 1000)), {}
+    if case == "pad-to":
+        return M.powerlaw(500, avg_nnz=6, seed=4), {"pad_to": 4096}
+    if case == "degenerate":  # no entries: one sentinel entry is stored
+        return sp.csr_matrix((3, 4)), {}
+    # under the threshold: fewer than two entries a row on average
+    return sp.random(500, 500, density=0.003, random_state=5, format="csr"), {}
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit", "vmap"])
+@pytest.mark.parametrize("case", ["powerlaw-empty-rows", "first-last-empty",
+                                  "one-row", "pad-to", "degenerate",
+                                  "under-threshold"])
+def test_coo_plain_row_sums(case, mode):
+    """``coo/plain`` with its row pointer against scipy in f64 and against
+    the scatter-add (the same container without the pointer), eagerly,
+    under jit and under vmap (SpMM: every column bit-identical to its own
+    call). The in-place row sums are checked on every case, and the lane
+    engages them exactly where the rows are long enough; under that it is
+    the scatter, bit for bit."""
+    import dataclasses
+
+    from repro.core.spmv import (SEGMENTED_MIN_ROW_NNZ, _sorted_row_sums,
+                                 coo_spmv_plain)
+
+    s, kw = _coo_row_sum_case(case)
+    A = from_dense(s, "coo", **kw)
+    scatter = dataclasses.replace(A, rowptr=None)
+    nrows, ncols = A.shape
+    X = np.random.default_rng(12).standard_normal((ncols, 3)).astype(np.float32)
+    if mode == "vmap":
+        lane = jax.jit(jax.vmap(coo_spmv_plain, in_axes=(None, 1), out_axes=1))
+        Y, Y0 = lane(A, X), lane(scatter, X)
+        for j in range(X.shape[1]):
+            np.testing.assert_array_equal(Y[:, j], jax.jit(coo_spmv_plain)(A, X[:, j]))
+        y, y0 = Y[:, 0], Y0[:, 0]
+    else:
+        lane = coo_spmv_plain if mode == "eager" else jax.jit(coo_spmv_plain)
+        y, y0 = lane(A, X[:, 0]), lane(scatter, X[:, 0])
+    x = X[:, 0].astype(np.float64)
+    want = s @ x
+    # a pairwise sum of a row's products: log2(row length) roundings at most
+    bound = 32 * np.finfo(np.float32).eps * (abs(s) @ np.abs(x)) + 1e-30
+    sums = _sorted_row_sums(A.row, A.val * X[:, 0][A.col], A.rowptr)
+    for got in (y, y0, sums):
+        assert np.all(np.abs(np.asarray(got, np.float64) - want) <= bound)
+    assert np.all(np.abs(np.asarray(y, np.float64) - np.asarray(y0)) <= bound)
+    if A.nnz < SEGMENTED_MIN_ROW_NNZ * nrows:
+        np.testing.assert_array_equal(y, y0)
+
+
+def test_coo_segmented_rows_counter():
+    """A recording counts the rows of each traced ``coo/plain`` SpMV that
+    sums rows in place: all of a graph-like matrix's, none of HPCG's
+    injection transfers (one entry a row, or fewer)."""
+    from repro.core.spmv import coo_spmv_plain
+    from repro.solvers.mg import injection_operators
+
+    g = from_dense(M.powerlaw(4096, avg_nnz=16, seed=5), "coo")
+    with obs.recording() as rec:
+        jax.jit(coo_spmv_plain)(g, jnp.ones((4096,), jnp.float32))
+    assert rec.counts == {"coo_spmv.segmented_rows": 4096}
+    R, P = injection_operators(16, 16, 16)
+    for op in (R, P):
+        with obs.recording() as rec:
+            jax.jit(coo_spmv_plain)(op.container, jnp.ones((op.shape[1],), jnp.float32))
+        assert rec.counts == {}
+
+
+def test_coo_row_pointer_is_validated():
+    """The container check refuses a row pointer that does not point into
+    the sorted rows; ``to_coo``'s own passes, pad sentinels included."""
+    import dataclasses
+
+    from repro.core import SparseInputError, validate_container
+
+    A = from_dense(M.powerlaw(300, avg_nnz=6, seed=2), "coo", pad_to=512)
+    validate_container(A)
+    for bad in (dataclasses.replace(A, rowptr=A.rowptr.at[5].add(1)),
+                dataclasses.replace(A, row=A.row[::-1])):
+        with pytest.raises(SparseInputError, match="rowptr"):
+            validate_container(bad)
+
+
 @pytest.mark.parametrize("bs", [8, 32])
 @pytest.mark.parametrize("nf", [1, 9, 64])
 def test_bsr_spmm_sweep(bs, nf):

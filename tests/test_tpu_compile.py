@@ -39,6 +39,8 @@ RESIDENT = ExecutionPolicy(backends=("pallas",), allow_fallback=False)
 COO_RESIDENT_GRID = 20
 #: BSR SpMV/SpMM shapes of a 32768-row, 32-edge, 5%-block-dense matrix
 BSR_ROWS, BSR_BS, BSR_WIDTH = 32768, 32, 64
+#: PageRank's graph (GAP kron at scale 20): stored entries and rows
+GRAPH_NNZ, GRAPH_ROWS = 31_400_000, 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -216,6 +218,26 @@ def test_bsr_spmm_compiles_for_v5e(nf, one_chip):
              S((nbrows, BSR_WIDTH), jnp.int32),
              S((nbrows, BSR_WIDTH, BSR_BS, BSR_BS), jnp.float32),
              S((BSR_ROWS, nf), jnp.float32))
+
+
+def test_coo_plain_row_sums_compile_for_v5e(one_chip):
+    """``coo/plain`` with its row pointer at the kron-20 graph's shapes: the
+    rows are summed in place (no scatter in the program), one gather of x
+    and one of the row ends, and the temporaries stay under 1 GB."""
+    import re
+
+    from repro.core.formats import COO
+    from repro.core.spmv import coo_spmv_plain
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    A = COO(S((GRAPH_NNZ,), jnp.int32), S((GRAPH_NNZ,), jnp.int32),
+            S((GRAPH_NNZ,), jnp.float32), (GRAPH_ROWS, GRAPH_ROWS), None,
+            S((GRAPH_ROWS + 1,), jnp.int32))
+    compiled = jax.jit(coo_spmv_plain).lower(A, S((GRAPH_ROWS,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r" scatter\(", text)
+    assert len(re.findall(r" gather\(", text)) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_rehearsal_shapes_are_the_stencils(containers):
